@@ -462,6 +462,8 @@ def main(argv=None):
                     help="comma-separated subset of benchmarks to run "
                          "(m4, flowsim_fast, serve, accuracy; default: all)")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     benches = {
         "BENCH_m4.json": ("m4", lambda: measure_m4(

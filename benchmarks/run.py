@@ -46,6 +46,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="smaller flow counts (CI)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
     n = 150 if args.fast else 300
 
     from . import (table1_flowsim_vs_ns3, table3_accuracy, table4_scaling,

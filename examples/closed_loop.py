@@ -32,6 +32,8 @@ def main():
     ap.add_argument("--flows-per-rack", type=int, default=30)
     ap.add_argument("--limits", type=int, nargs="+", default=[1, 3, 5])
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     topo = FatTree(num_racks=args.racks, hosts_per_rack=4, num_spines=2)
     config = NetConfig(cc="dctcp")

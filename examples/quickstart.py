@@ -25,6 +25,8 @@ def main():
     ap.add_argument("--epochs", type=int, default=8)
     ap.add_argument("--workdir", default="results")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     cfg = M4Config(hidden=64, gnn_dim=48, mlp_hidden=32,
                    snap_flows=16, snap_links=48)

@@ -44,6 +44,8 @@ def main():
                     help="dry-run JSON (default: first train cell found)")
     ap.add_argument("--ranks", type=int, default=16)
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     cell = args.cell or sorted(
         glob.glob("results/dryrun/*train_4k_16x16.json"))[0]

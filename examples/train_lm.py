@@ -32,6 +32,8 @@ def main():
     ap.add_argument("--steps", type=int, default=None)
     ap.add_argument("--ckpt-dir", default="results/lm_ckpt")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     cfg = cfg_ci() if args.ci else cfg_100m()
     steps = args.steps or (60 if args.ci else 300)

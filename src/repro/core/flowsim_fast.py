@@ -225,6 +225,16 @@ def run_flowsim_fast(topo, flows, probes: ProbeConfig = None):
     return _result(topo, flows, fct_abs, wall, series)
 
 
+def stack_scenarios(scenarios):
+    """Pad (topo, flows) scenarios to one incidence shape and stack the
+    `_event_scan` inputs along a leading scenario axis."""
+    n_max = max(len(flows) for _, flows in scenarios)
+    l_max = max(topo.num_links for topo, _ in scenarios)
+    packed = [_pack(topo, flows, n_total=n_max, l_total=l_max)
+              for topo, flows in scenarios]
+    return [jnp.asarray(np.stack(col)) for col in zip(*packed)]
+
+
 def run_flowsim_fast_batch(scenarios, probes: ProbeConfig = None):
     """One vmapped compile over B (topo, flows) scenarios padded to the
     largest flow/link count. Returns a list of FlowSimResult. Probed
@@ -235,9 +245,7 @@ def run_flowsim_fast_batch(scenarios, probes: ProbeConfig = None):
         return []
     n_max = max(len(flows) for _, flows in scenarios)
     l_max = max(topo.num_links for topo, _ in scenarios)
-    packed = [_pack(topo, flows, n_total=n_max, l_total=l_max)
-              for topo, flows in scenarios]
-    stacked = [jnp.asarray(np.stack(col)) for col in zip(*packed)]
+    stacked = stack_scenarios(scenarios)
     mode = dispatch.resolve_mode()
     D = jax.local_device_count()
     t0 = time.perf_counter()

@@ -23,6 +23,15 @@ import jax.numpy as jnp
 
 from ..nn import gru_init, linear, linear_init, mlp, mlp_init
 
+# Precision of every f32 matmul of m4, Pallas kernels included: the event
+# step (`core.simulate`) and the training scan (`core.training`) are
+# traced under `jax.default_matmul_precision(MATMUL_PRECISION)`. The TPU's
+# default rounds matmul inputs below f32: on the chip that put `run_many`
+# up to 1e-3 from `run` on single flows, and the 4-chip training gradient
+# 2e-3 from the one-chip one. "highest" keeps f32 in XLA and in Mosaic;
+# on the CPU it changes nothing.
+MATMUL_PRECISION = "highest"
+
 
 @dataclass(frozen=True)
 class M4Config:

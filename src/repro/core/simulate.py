@@ -43,8 +43,9 @@ import numpy as np
 
 from ..kernels.dispatch import canonicalize_cfg
 from ..nn import mlp
-from .model import (M4Config, predict_queue, predict_size, predict_sldn,
-                    spatial_update, temporal_update)
+from .model import (MATMUL_PRECISION, M4Config, predict_queue,
+                    predict_size, predict_sldn, spatial_update,
+                    temporal_update)
 from .probes import (M4_CHANNELS, ProbeConfig, finalize as _probe_finalize,
                      init_buffers as _probe_init, normalize_probes,
                      record as _probe_record)
@@ -176,6 +177,10 @@ def make_event_step(cfg: M4Config, static, num_links: int,
 
     def event_step(params, state, t_ev, fid, is_arrival):
         """Process one flow-level event; returns (state, sldn_pred, snap)."""
+        with jax.default_matmul_precision(MATMUL_PRECISION):
+            return _event_step(params, state, t_ev, fid, is_arrival)
+
+    def _event_step(params, state, t_ev, fid, is_arrival):
         flow_links = static["flow_links"]
         cfg_vec = static["cfg_vec"]
         N = flow_links.shape[0]
@@ -264,7 +269,8 @@ def init_sim_state(params, cfg: M4Config, static, N, num_links: int):
     l_in = jnp.concatenate(
         [static["link_feat"][:L],
          jnp.broadcast_to(cfg_vec, (L, cfg_vec.shape[0]))], -1)
-    link_h = jnp.tanh(mlp(params["link_init"], l_in))
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        link_h = jnp.tanh(mlp(params["link_init"], l_in))
     link_h = jnp.concatenate([link_h, jnp.zeros((1, H), jnp.float32)], 0)
     return dict(
         flow_h=jnp.zeros((N + 1, H), jnp.float32),
@@ -593,6 +599,30 @@ def simulate_open_loop(params, cfg: M4Config, topo, net_config, flows, *,
                     compile_wall=compile_wall, probes=series)
 
 
+def stack_scenarios(cfg: M4Config, scenarios):
+    """Pad (topo, net_config, flows) scenarios to one arena shape and
+    stack them: returns (static, arrival order, arrival times, padded
+    link count, per-scenario ideal FCTs) — the inputs of the batched
+    scans, whose leading axis is the scenario."""
+    n_max = max(len(flows) for _, _, flows in scenarios)
+    l_max = max(topo.num_links for topo, _, _ in scenarios)
+    k_max = max(max_link_degree(flows, cfg.max_path)
+                for _, _, flows in scenarios)
+    statics, orders, times, ideals = [], [], [], []
+    for topo, net_config, flows in scenarios:
+        static, _, ideal = make_static(topo, flows, net_config, cfg,
+                                       n_total=n_max, l_total=l_max,
+                                       k_total=k_max)
+        order, t = _arrival_order(static)
+        statics.append(static)
+        orders.append(order)
+        times.append(t)
+        ideals.append(ideal)
+    batched = {k: jnp.stack([s[k] for s in statics]) for k in statics[0]}
+    return (batched, jnp.asarray(np.stack(orders)),
+            jnp.asarray(np.stack(times)), l_max, ideals)
+
+
 def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
                              snapshot_impl="incremental",
                              probes: ProbeConfig = None) -> list:
@@ -612,23 +642,9 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
     if not scenarios:
         return []
     n_max = max(len(flows) for _, _, flows in scenarios)
-    l_max = max(topo.num_links for topo, _, _ in scenarios)
-    k_max = max(max_link_degree(flows, cfg.max_path)
-                for _, _, flows in scenarios)
-    statics, orders, times, ideals, counts = [], [], [], [], []
-    for topo, net_config, flows in scenarios:
-        static, _, ideal = make_static(topo, flows, net_config, cfg,
-                                       n_total=n_max, l_total=l_max,
-                                       k_total=k_max)
-        order, t = _arrival_order(static)
-        statics.append(static)
-        orders.append(order)
-        times.append(t)
-        ideals.append(ideal)
-        counts.append(len(flows))
-    batched = {k: jnp.stack([s[k] for s in statics]) for k in statics[0]}
-    order_b = jnp.asarray(np.stack(orders))
-    times_b = jnp.asarray(np.stack(times))
+    batched, order_b, times_b, l_max, ideals = stack_scenarios(cfg,
+                                                               scenarios)
+    counts = [len(flows) for _, _, flows in scenarios]
     D = jax.local_device_count()
     t0 = time.perf_counter()
     bufs = None

@@ -21,8 +21,9 @@ import jax
 import jax.numpy as jnp
 
 from ..optim import adamw_update, clip_by_global_norm
-from .model import (M4Config, predict_queue, predict_size, predict_sldn,
-                    spatial_update, temporal_update)
+from .model import (MATMUL_PRECISION, M4Config, predict_queue,
+                    predict_size, predict_sldn, spatial_update,
+                    temporal_update)
 
 
 def _as_jnp(b):
@@ -31,6 +32,11 @@ def _as_jnp(b):
 
 def event_scan_losses(params, cfg: M4Config, b):
     """Scan all K events of one sim; returns per-head mean L1 losses."""
+    with jax.default_matmul_precision(MATMUL_PRECISION):
+        return _event_scan_losses(params, cfg, b)
+
+
+def _event_scan_losses(params, cfg: M4Config, b):
     import dataclasses
     # training differentiates through the event step: force the jnp kernel
     # path — the Pallas kernels (repro.kernels.*) define no VJP, so a cfg

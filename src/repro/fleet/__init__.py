@@ -1,7 +1,7 @@
 """repro.fleet — fault-tolerant sweep orchestration.
 
 Shards any `repro.scenarios` suite (or dataset build) across a pool of
-supervised spawn workers, with the blobstore as the only coordination
+supervised worker processes, with the blobstore as the only coordination
 spine: atomic lease files claim work, results land in the existing
 content-addressed caches (so a re-launched fleet resumes from whatever
 completed), and a supervisor handles retry/backoff, poison quarantine,
@@ -23,9 +23,8 @@ from .coord import Coordinator
 from .jobs import (DatasetJob, FleetJob, SweepJob, dataset_tasks,
                    sweep_job_for, sweep_tasks)
 from .metrics import FleetMetrics
-from .supervisor import (FleetConfig, default_coord_dir, run_fleet,
-                         task_set_digest)
-from .worker import worker_entry
+from .supervisor import (DeviceBusyError, FleetConfig, default_coord_dir,
+                         run_fleet, task_set_digest)
 
 __all__ = [
     "ChaosMonkey", "Fault", "FaultPlan", "parse_plan",
@@ -33,6 +32,6 @@ __all__ = [
     "DatasetJob", "FleetJob", "SweepJob",
     "dataset_tasks", "sweep_job_for", "sweep_tasks",
     "FleetMetrics",
-    "FleetConfig", "default_coord_dir", "run_fleet", "task_set_digest",
-    "worker_entry",
+    "DeviceBusyError", "FleetConfig", "default_coord_dir", "run_fleet",
+    "task_set_digest",
 ]

@@ -66,6 +66,8 @@ def main(argv=None) -> int:
     ap.add_argument("--expect-clean", action="store_true",
                     help="fail if any chunk was poisoned")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     knobs = {}
     if args.num_flows is not None:

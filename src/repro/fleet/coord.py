@@ -9,6 +9,9 @@ Everything workers and the supervisor agree on lives in one directory
     <coord>/poison/<task>.json    quarantine manifests (permanent)
     <coord>/chaos/                one-shot fault fired-markers
     <coord>/metrics.json          supervisor's final FleetMetrics
+    <coord>/worker_args.pkl       what the supervisor hands each worker
+    <coord>/ready/<owner>         a worker finished starting up
+    <coord>/start.hold            present while the first pool starts
 
 All records are plain JSON written tmp+rename, so readers never see a
 torn file. Markers carry *bookkeeping*; the results themselves go
@@ -135,6 +138,40 @@ class Coordinator:
                 if rec is not None:
                     out.append(rec)
         return out
+
+    # ------------------------------------------------------------ start
+    # Workers take about a second to start and a chunk can take
+    # milliseconds, so the first worker up would otherwise claim most of
+    # the work. The supervisor holds the pool at a start line until every
+    # worker it launched together is ready.
+    @property
+    def _hold(self) -> str:
+        return os.path.join(self.root, "start.hold")
+
+    def hold_start(self):
+        """Raise the start line and forget earlier launches' workers."""
+        open(self._hold, "w").close()
+        rdir = os.path.join(self.root, "ready")
+        os.makedirs(rdir, exist_ok=True)
+        for name in os.listdir(rdir):
+            os.remove(os.path.join(rdir, name))
+
+    def release_start(self):
+        try:
+            os.remove(self._hold)
+        except OSError:
+            pass
+
+    def start_held(self) -> bool:
+        return os.path.exists(self._hold)
+
+    def mark_ready(self, owner: str):
+        path = os.path.join(self.root, "ready", owner)
+        os.makedirs(os.path.dirname(path), exist_ok=True)
+        open(path, "w").close()
+
+    def is_ready(self, owner: str) -> bool:
+        return os.path.exists(os.path.join(self.root, "ready", owner))
 
     # ----------------------------------------------------------- metrics
     def write_metrics(self, metrics: Dict):

@@ -29,6 +29,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 Task = Tuple[str, dict]     # (task_id, payload)
 
+# `repro.sim` backends that compute with JAX on the default device
+DEVICE_BACKENDS = frozenset({"m4", "flowsim_fast"})
+
 
 def _numpyify(tree):
     """Recursively convert array leaves to numpy so a jax params pytree
@@ -45,7 +48,14 @@ def _numpyify(tree):
 
 class FleetJob:
     """Base strategy: subclasses define the three methods below and must
-    be picklable (spawn start method ships them to workers by value)."""
+    be picklable (the supervisor ships them to workers by value).
+
+    `needs_device` says whether the job computes on the accelerator; the
+    supervisor gives such a job the device in at most one worker and
+    runs every other job's workers on the CPU (see
+    `repro.fleet.supervisor._worker_env`)."""
+
+    needs_device = False
 
     def run(self, payload: dict) -> None:
         """Compute the task and persist results through the blobstore."""
@@ -88,6 +98,11 @@ class SweepJob(FleetJob):
     # `done_extra` scores each scenario against the oracle's cached result
     # and the divergence rides into the task's done marker
     diff_against: Optional[str] = None
+
+    @property
+    def needs_device(self) -> bool:
+        """The jax backends run on the accelerator; the rest are numpy."""
+        return self.backend_name in DEVICE_BACKENDS
 
     def _backend(self):
         be = getattr(self, "_backend_obj", None)

@@ -34,6 +34,14 @@ Correctness never rests on the supervisor's bookkeeping: results are
 content-addressed atomic blobs, so the worst a wrong decision (broken
 lease, double spawn) can cause is duplicate compute writing identical
 bytes.
+
+Workers are fresh interpreters started as `python -m repro.fleet.worker`
+(see `_worker_env` for what they inherit), so how the parent itself was
+launched (a script, `-m`, stdin, a pytest-xdist worker) never matters.
+An accelerator belongs to one process at a time: a job that needs it
+(`FleetJob.needs_device`) runs in one worker, and only when this
+process holds no device; every other worker runs with
+`JAX_PLATFORMS=cpu`.
 """
 from __future__ import annotations
 
@@ -41,7 +49,10 @@ import dataclasses
 import hashlib
 import logging
 import os
+import pickle
 import signal
+import subprocess
+import sys
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Set, Tuple
@@ -92,6 +103,70 @@ def default_coord_dir(base_root: str, tasks: List[Task]) -> str:
     return os.path.join(base_root, "fleet", task_set_digest(tasks))
 
 
+# <src>/repro/fleet/supervisor.py -> <src>: put on each worker's PYTHONPATH
+_SRC_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+# bound on the wait for a freshly launched pool to reach its start line
+START_TIMEOUT_S = 60.0
+
+
+class DeviceBusyError(RuntimeError):
+    """A job that needs the accelerator cannot get it in a worker: this
+    process already holds it, or more than one worker was asked for."""
+
+
+def _held_platform() -> Optional[str]:
+    """Platform of the JAX backend this process has initialized, or None
+    if it has not touched one (and so holds no device)."""
+    if "jax" not in sys.modules:
+        return None
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return None
+    import jax
+    return jax.default_backend()
+
+
+def _worker_env(job: FleetJob, workers: int) -> Dict[str, str]:
+    """What every worker process of one run gets on top of this
+    process's environment at spawn time (which carries the tracing
+    variables).
+
+    Workers import `repro` from this checkout's `src`. A job that needs
+    no device runs its workers with `JAX_PLATFORMS=cpu`, so any number
+    of them can import JAX beside a process that owns the chip. A job
+    that needs the device keeps the CPU when the run is pinned to it
+    (`JAX_PLATFORMS=cpu`) or when this process's JAX found nothing but
+    the CPU; otherwise it gets the device in a single worker, and only
+    if this process holds none — else `DeviceBusyError`, before any
+    worker starts, instead of workers that hang on a taken chip."""
+    env = {"PYTHONPATH": os.pathsep.join(
+        [_SRC_ROOT] + [p for p in os.environ.get("PYTHONPATH", "").split(
+            os.pathsep) if p])}
+    if not job.needs_device:
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return env
+    held = _held_platform()
+    if held == "cpu":
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
+    if held is not None:
+        raise DeviceBusyError(
+            f"this process already holds the {held} device, so no fleet "
+            "worker can use it; run the sweep in-process (fleet=None) or "
+            "start the fleet from a process that has not touched JAX")
+    if workers > 1:
+        raise DeviceBusyError(
+            f"{workers} workers asked for a job that needs the "
+            "accelerator, which one process at a time can own; use "
+            "workers=1, or JAX_PLATFORMS=cpu to run them all on the CPU")
+    return env
+
+
 def _pid_alive(pid: int) -> bool:
     try:
         os.kill(pid, 0)
@@ -114,9 +189,7 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
         if log:
             log(f"[fleet] {msg}")
 
-    import multiprocessing as mp
-    ctx = mp.get_context("spawn")   # never fork a live XLA runtime
-
+    env = _worker_env(job, config.workers)
     coord = Coordinator(config.coord_dir)
     payloads: Dict[str, dict] = dict(tasks)
     task_ids = [tid for tid, _ in tasks]
@@ -168,18 +241,23 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
             "already complete")
 
     # --------------------------------------------------- worker pool
-    procs: Dict[int, object] = {}       # worker index -> Process
+    # what every worker needs, pickled once (this process wrote it, the
+    # workers read it); each worker gets the path and its own index
+    args_path = os.path.join(coord.root, "worker_args.pkl")
+    tmp = f"{args_path}.{os.getpid()}.tmp"
+    with open(tmp, "wb") as f:
+        pickle.dump((coord.root, job, tasks, config.chaos,
+                     config.heartbeat_s, config.poll_s), f)
+    os.replace(tmp, args_path)
+    procs: Dict[int, subprocess.Popen] = {}   # worker index -> process
     next_index = 0
 
     def spawn():
         nonlocal next_index
         idx = next_index
-        p = ctx.Process(
-            target=_entry, name=f"fleet-w{idx}",
-            args=(idx, coord.root, job, tasks, config.chaos,
-                  config.heartbeat_s, config.poll_s),
-            daemon=True)
-        p.start()
+        p = subprocess.Popen(
+            [sys.executable, "-m", "repro.fleet.worker", args_path,
+             str(idx)], env={**os.environ, **env})
         procs[idx] = p
         next_index += 1
         metrics.workers_spawned += 1
@@ -200,11 +278,25 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
     requeue_at: Dict[str, float] = {}
     flagged: Set[Tuple[str, int]] = set()   # straggler (task, attempt)
 
+    def start_pool():
+        """Launch a whole pool at once, then open the start line when
+        every worker is ready (or has died, or START_TIMEOUT_S passed)."""
+        coord.hold_start()
+        try:
+            for _ in range(min(config.workers, max(len(pending), 1))):
+                spawn()
+            t_end = time.monotonic() + START_TIMEOUT_S
+            while time.monotonic() < t_end and any(
+                    p.poll() is None and not coord.is_ready(f"w{idx}")
+                    for idx, p in procs.items()):
+                time.sleep(config.poll_s)
+        finally:
+            coord.release_start()
+
     try:
         while pending:
             if not procs and pending:
-                for _ in range(min(config.workers, max(len(pending), 1))):
-                    spawn()
+                start_pool()
             now = time.monotonic()
 
             # ---- completions / poisons / errors
@@ -278,11 +370,12 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
 
             # ---- worker health: collect exits, requeue orphaned leases
             for idx, p in list(procs.items()):
-                if p.exitcode is None:
+                code = p.poll()
+                if code is None:
                     continue
                 del procs[idx]
-                if p.exitcode != 0:
-                    say(f"worker w{idx} exited {p.exitcode}")
+                if code != 0:
+                    say(f"worker w{idx} exited {code}")
                     for tid in coord.leases.active():
                         info = coord.leases.owner(tid) or {}
                         if (info.get("owner") == f"w{idx}"
@@ -291,7 +384,7 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
                             metrics.lease_breaks += 1
                             coord.synthetic_error(
                                 tid, f"w{idx}",
-                                f"worker exited {p.exitcode} mid-chunk")
+                                f"worker exited {code} mid-chunk")
 
             # ---- keep the pool full while work remains
             while pending and len(procs) < min(config.workers,
@@ -317,12 +410,13 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
     finally:
         # workers exit 0 on their own once everything is terminal;
         # anything still running after a grace period gets killed
+        grace = 2 * config.poll_s + config.heartbeat_s
         for p in procs.values():
-            p.join(timeout=2 * config.poll_s + config.heartbeat_s)
-        for p in procs.values():
-            if p.exitcode is None:
+            try:
+                p.wait(timeout=grace)
+            except subprocess.TimeoutExpired:
                 p.kill()
-                p.join(timeout=5)
+                p.wait(timeout=5)
 
     metrics.done = sum(coord.is_done(tid) for tid in task_ids)
     metrics.poisoned = sum(coord.is_poisoned(tid) for tid in task_ids)
@@ -341,10 +435,3 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
         f"{metrics.poisoned} poisoned, {metrics.retried} retried, "
         f"{metrics.kills} kill(s), {metrics.wall_s:.1f}s")
     return metrics
-
-
-def _entry(*args):
-    """Spawn trampoline: import inside the child so the worker module
-    resolves in the fresh interpreter."""
-    from .worker import worker_entry
-    worker_entry(*args)

@@ -1,6 +1,10 @@
 """Fleet worker process (`repro.fleet.worker`).
 
-`worker_entry` is the spawn target: it scans the task list, claims
+The supervisor starts each worker as
+
+    python -m repro.fleet.worker <coord>/worker_args.pkl <index>
+
+and `worker_entry` does the work: it scans the task list, claims
 unowned tasks through `LeaseDir` (O_CREAT|O_EXCL — the filesystem picks
 exactly one winner), heartbeats the lease from a daemon thread while the
 chunk runs, writes results through the job's blobstore, *verifies* them
@@ -21,6 +25,9 @@ pure-python backends never pay XLA startup.
 """
 from __future__ import annotations
 
+import os
+import pickle
+import sys
 import threading
 import time
 from typing import List, Optional, Tuple
@@ -59,6 +66,12 @@ def worker_entry(worker_index: int, coord_root: str, job: FleetJob,
         k = worker_index % len(tasks)
         tasks = tasks[k:] + tasks[:k]
     claims = 0
+    # wait at the start line with the rest of the pool; a supervisor that
+    # died meanwhile (this process was re-parented) releases it too
+    coord.mark_ready(owner)
+    supervisor = os.getppid()
+    while coord.start_held() and os.getppid() == supervisor:
+        time.sleep(poll_s)
 
     while True:
         all_terminal = True
@@ -132,3 +145,17 @@ def worker_entry(worker_index: int, coord_root: str, job: FleetJob,
             wspan.end()
             return
         time.sleep(poll_s)
+
+
+def main(argv=None) -> None:
+    """Module entry point: unpickle the run's arguments (written by this
+    worker's own supervisor) and serve as worker `<index>`."""
+    args_path, index = sys.argv[1:] if argv is None else argv
+    with open(args_path, "rb") as f:
+        coord_root, job, tasks, plan, heartbeat_s, poll_s = pickle.load(f)
+    worker_entry(int(index), coord_root, job, tasks, plan, heartbeat_s,
+                 poll_s)
+
+
+if __name__ == "__main__":
+    main()

@@ -6,14 +6,14 @@ GRU cell (`repro.kernels.fused_gru`), the bipartite GraphSAGE round
 (`repro.kernels.waterfill`). Three modes:
 
     pallas      compiled Pallas kernels. Requires a TPU; requesting it on
-                any other platform silently resolves to "interpret"
-                (same kernels, bit-faithful, but run through the Pallas
-                interpreter lowered to plain XLA ops).
+                any other platform raises, so a run that asked for the
+                compiled kernels can never quietly execute something else.
     xla         the pure-jnp reference math (segment-sum GNN, unfused
                 GRU, jnp row-min). The fastest choice on CPU.
     interpret   the Pallas kernels under the interpreter on any platform
-                (used by CI to exercise the kernel code paths without a
-                TPU).
+                (same kernels, run through the Pallas interpreter lowered
+                to plain XLA ops). CPU tests request it explicitly to
+                exercise the kernel code paths without a TPU.
 
 Resolution order: a concrete caller-requested mode (e.g. a pinned
 ``M4Config.kernel_mode``) beats the ``REPRO_KERNELS`` environment
@@ -53,6 +53,7 @@ def resolve_mode(requested: str | None = None) -> str:
     fingerprints from the executed path. ``REPRO_KERNELS`` fills in when
     the request is None (every default construction), then the platform
     probe. Returns one of "pallas" (TPU only), "xla", "interpret".
+    Raises ValueError when "pallas" is requested off-TPU.
     """
     if requested is None:
         env = os.environ.get(ENV_VAR, "").strip().lower() or None
@@ -66,7 +67,10 @@ def resolve_mode(requested: str | None = None) -> str:
         raise ValueError(
             f"kernel mode {requested!r} invalid; choose one of {MODES}")
     if requested == "pallas" and _platform() != "tpu":
-        requested = "interpret"  # compiled Pallas needs the Mosaic backend
+        raise ValueError(
+            f"kernel mode 'pallas' needs a TPU, but JAX runs on "
+            f"{_platform()!r}; request 'interpret' to run the Pallas "
+            "kernels under the interpreter, or 'xla'")
     # count resolutions per concrete mode so an obs snapshot shows which
     # kernel path a run actually dispatched (lazy import: dispatch must
     # stay importable before the obs package loads)

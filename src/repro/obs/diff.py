@@ -389,6 +389,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--worst", type=int, default=8,
                     help="how many worst specs to embed in the report")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     from ..scenarios.suites import get_suite
     suite = get_suite(args.suite, num_flows=args.num_flows)

@@ -63,6 +63,8 @@ def main(argv=None) -> int:
     ap.add_argument("--cache-dir", default=None,
                     help="on-disk result cache directory (off by default)")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
 
     if args.list or not args.suite:
         print("available suites:")

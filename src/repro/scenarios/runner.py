@@ -173,7 +173,7 @@ class SweepRunner:
                     len(miss))
         fleet_metrics = None
         if miss and self.fleet is not None and use_cache:
-            fleet_metrics = self._run_fleet(name, specs, requests, keys,
+            fleet_metrics = self._run_fleet(specs, requests, keys,
                                             miss, results, request_options)
         elif miss:
             if self.fleet is not None:
@@ -207,29 +207,16 @@ class SweepRunner:
                            wall_time=time.perf_counter() - t0,
                            fleet=fleet_metrics)
 
-    def _run_fleet(self, name, specs, requests, keys, miss, results,
+    def _run_fleet(self, specs, requests, keys, miss, results,
                    request_options):
         """Dispatch the cache misses through a supervised worker fleet;
         fills `results` in place from the cache afterwards and returns
         the run's metrics dict. Scenarios whose chunk was poisoned stay
-        None. Falls back to the in-process path when spawn workers can't
-        start (no importable __main__ — stdin/REPL parents)."""
+        None. A jax backend's workers need the accelerator, so they run
+        one at a time and only if this process holds no device
+        (`repro.fleet.DeviceBusyError` otherwise)."""
         from ..fleet import default_coord_dir, run_fleet, sweep_job_for, \
             sweep_tasks
-        from ..train.data import _pool_usable
-        if not _pool_usable():
-            chunks = 1 if not self.chunk_size else \
-                -(-len(miss) // self.chunk_size)
-            with obs_phase("sweep.simulate",
-                           attrs={"backend": self.backend.name,
-                                  "n": len(miss)}), \
-                    no_retrace(allowed=chunks, label=f"sweep '{name}'"):
-                fresh = self.backend.run_chunked([requests[i] for i in miss],
-                                                 self.chunk_size)
-            for i, res in zip(miss, fresh):
-                results[i] = res
-                self.cache.put(keys[i], res)
-            return None
         oracle_fp = self.diff_against
         if oracle_fp is not None and hasattr(oracle_fp, "fingerprint"):
             oracle_fp = oracle_fp.fingerprint()

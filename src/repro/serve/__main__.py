@@ -185,6 +185,8 @@ def main(argv=None) -> int:
     ap.add_argument("--verbose", action="store_true",
                     help="log every HTTP request")
     args = ap.parse_args(argv)
+    from ..runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
     if args.smoke:
         return smoke(args)
     return serve_forever(args)

@@ -45,6 +45,7 @@ single wall-clock sleep.
 from __future__ import annotations
 
 import hashlib
+import logging
 import threading
 import time
 from concurrent.futures import Future, InvalidStateError
@@ -57,6 +58,8 @@ from ..scenarios.cache import ResultCache, result_key
 from ..sim import Backend, SimRequest, SimResult
 from .clock import Clock, MonotonicClock
 from .metrics import ServiceMetrics, merge_snapshots
+
+logger = logging.getLogger("repro.serve")
 
 
 def retry_after_jitter(base_s: float, key: str) -> float:
@@ -489,9 +492,16 @@ class SimService:
         try:
             results = self._execute(lane, requests, shape,
                                     windows)[:len(live)]
-        except Exception:
+        except Exception as exc:
             # the batch as a whole failed — isolate per request so one
-            # poisoned scenario can't take its flush-mates down with it
+            # poisoned scenario can't take its flush-mates down with it.
+            # Log the cause: the lone re-runs may all succeed and would
+            # otherwise hide a batched program that cannot run at all
+            # (a compile error, or a device out of memory)
+            logger.error("serve lane %r: batch of %d at shape %s failed "
+                         "(%s: %s); re-running each request alone",
+                         lane.name, len(live), shape, type(exc).__name__,
+                         exc, exc_info=True)
             self._isolate(lane, live)
             return
         lane.metrics.count("batches")

@@ -89,6 +89,8 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
+    from ..runtime.compile_cache import use_persistent_cache
+    use_persistent_cache()
     import os
     import shutil
 

@@ -12,12 +12,14 @@ event-tensor layout (`snap_flows`/`snap_links`/`max_path`, the event cap)
 CI caches the store directory under the aggregate `dataset_key`.
 
 Cache misses fan out across worker *processes* (the DES is pure-Python
-and CPU-bound, so threads won't do); workers are spawned, not forked —
-the parent usually has JAX initialized, and forking a live XLA runtime
-is undefined behaviour. The pool is a `repro.fleet` run (one task per
-missing shard, the store as result channel), so multi-worker builds
-inherit lease-based claiming, crash/straggler reaping and retry with
-backoff instead of dying with the first worker exception — and a
+and CPU-bound, so threads won't do); workers are fresh interpreters, not
+forks — the parent usually has JAX initialized, and forking a live XLA
+runtime is undefined behaviour — and they run on the CPU, so any number
+of them can start beside a process that owns the chip. The pool is a
+`repro.fleet` run (one task per missing shard, the store as result
+channel), so multi-worker builds inherit lease-based claiming,
+crash/straggler reaping and retry with backoff instead of dying with the
+first worker exception — and a
 killed build resumes from whatever shards completed. Storage is
 `runtime.blobstore.BlobStore` — the same sharded content-addressed
 directory scheme, compression and atomic-write discipline as
@@ -27,7 +29,6 @@ corpora are safe.
 from __future__ import annotations
 
 import hashlib
-import os
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional, Sequence, Tuple
@@ -119,21 +120,6 @@ def _worker(args) -> Tuple[str, str]:
     return key, path
 
 
-def _pool_usable() -> bool:
-    """True when spawn()ed workers can actually start: the spawn start
-    method re-imports `__main__`, so a parent running from stdin or a
-    REPL (no importable main module) would wedge the pool with
-    FileNotFoundError bootstrap loops — build inline there instead."""
-    import sys
-    main = sys.modules.get("__main__")
-    if main is None:
-        return False
-    if getattr(main, "__spec__", None) is not None:   # python -m ...
-        return True
-    path = getattr(main, "__file__", None)
-    return bool(path) and os.path.exists(path)
-
-
 @dataclass
 class DatasetReport:
     """What one `build_dataset` call did (the cache-hit acceptance
@@ -195,11 +181,6 @@ def build_dataset(specs: Sequence, m4cfg: M4Config, root: str, *,
                 f" with {max(workers, 1)} worker(s)")
         use_pool = (fleet is not None or (workers and workers > 1)) \
             and len(miss) > 1
-        if use_pool and not _pool_usable():
-            if log:
-                log("[train.data] no importable __main__ (stdin/REPL) — "
-                    "spawn workers unavailable, building inline")
-            use_pool = False
         if use_pool:
             from ..fleet import (DatasetJob, FleetConfig, dataset_tasks,
                                  default_coord_dir, run_fleet)

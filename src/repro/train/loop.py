@@ -247,6 +247,9 @@ def make_bucket_step(m4cfg: M4Config, tc: TrainConfig, schedule) -> Callable:
         per = -(-B // D)
         w = jnp.concatenate([w, jnp.zeros((per * D - B,), jnp.float32)])
         return _pstep(params, opt, shard_leaves(bb, D), shard_leaves(w, D))
+    # the same update on one device: the reference the sharded step is
+    # checked against (`chip_smoke.py --chips 4`)
+    step.single_device = single_device_step
     return step
 
 

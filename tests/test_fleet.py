@@ -239,6 +239,73 @@ def test_exhausted_retries_poison(tmp_path):
     assert rec["attempts"] == 3 and "exhausted" in rec["why"]
 
 
+# ------------------------------------------------------- device ownership
+@pytest.mark.parametrize("backend, env, held, workers, want", [
+    # numpy backends never touch a device: any number of CPU workers
+    ("flowsim", None, "tpu", 4, "cpu"),
+    ("packet", "tpu", None, 4, "cpu"),
+    # jax backends follow the run onto the CPU when it is pinned there
+    # or when this process's JAX found nothing but the CPU ...
+    ("flowsim_fast", "cpu", "tpu", 4, "cpu"),
+    ("m4", None, "cpu", 4, "cpu"),
+    # ... and otherwise get the accelerator in one worker, inherited
+    ("m4", None, None, 1, None),
+    ("flowsim_fast", "tpu", None, 1, "tpu"),
+    # never beside a parent that holds the chip, never in two workers
+    ("m4", None, "tpu", 1, "held"),
+    ("flowsim_fast", None, None, 2, "workers"),
+])
+def test_worker_env_device_ownership(monkeypatch, backend, env, held,
+                                     workers, want):
+    from repro.fleet import DeviceBusyError, SweepJob
+    from repro.fleet import supervisor
+    if env is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", env)
+    monkeypatch.setattr(supervisor, "_held_platform", lambda: held)
+    job = SweepJob(backend_name=backend, cache_dir="unused")
+    if want in ("held", "workers"):
+        with pytest.raises(DeviceBusyError,
+                           match="already holds" if want == "held"
+                           else "workers asked"):
+            supervisor._worker_env(job, workers)
+        return
+    out = {**os.environ, **supervisor._worker_env(job, workers)}
+    assert out.get("JAX_PLATFORMS") == want
+    assert out["PYTHONPATH"].split(os.pathsep)[0] == \
+        os.path.join(REPO, "src")
+
+
+def test_dataset_job_workers_run_on_cpu(monkeypatch):
+    from repro.core.model import M4Config
+    from repro.fleet import DatasetJob
+    from repro.fleet import supervisor
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(supervisor, "_held_platform", lambda: "tpu")
+    job = DatasetJob(root="unused", m4cfg=M4Config())
+    assert not job.needs_device
+    assert supervisor._worker_env(job, 8)["JAX_PLATFORMS"] == "cpu"
+
+
+def test_busy_device_fails_before_any_worker_starts(monkeypatch, tmp_path):
+    """A device job beside a parent that holds the chip raises at once:
+    no worker is started, no task is poisoned."""
+    from repro.fleet import DeviceBusyError
+    from repro.fleet import supervisor
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(supervisor, "_held_platform", lambda: "tpu")
+    backend = get_backend("flowsim_fast")
+    specs = list(get_suite("smoke16", num_flows=8).limit(2))
+    reqs = [s.to_request() for s in specs]
+    keys = [result_key(r, backend) for r in reqs]
+    job = sweep_job_for(backend, str(tmp_path / "cache"))
+    cfg = fast_config(coord_dir=str(tmp_path / "coord"), workers=1)
+    with pytest.raises(DeviceBusyError):
+        run_fleet(sweep_tasks(specs, reqs, keys, 1), job, cfg)
+    assert not os.path.exists(tmp_path / "coord")
+
+
 # --------------------------------------------------------- CLI + acceptance
 def cli_cmd(cache_dir, extra, num_flows=8, workers=3):
     return [sys.executable, "-m", "repro.fleet", "--suite", "smoke16",

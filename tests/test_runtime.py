@@ -278,3 +278,38 @@ def test_restore_latest_loadable_rolls_back_past_corruption(tmp_path):
         open(blob, "wb").write(bytes(raw))
     with pytest.raises(FileNotFoundError, match="no loadable committed"):
         ckpt.restore_latest_loadable(d, {"x": jnp.zeros(50)})
+
+
+# ------------------------------------------------------- compile cache
+@pytest.fixture()
+def cache_config():
+    """Restore JAX's cache directory (and its once-per-process cache
+    state) after a test points it somewhere."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+    cc.reset_cache()
+
+
+def test_compile_cache_env_stays_in_charge(monkeypatch, tmp_path,
+                                           cache_config):
+    from repro.runtime import compile_cache
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.use_persistent_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_into_checkout(monkeypatch, cache_config):
+    from repro.runtime import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(repo, ".jax_cache")
+    # a fixed path: the same on every call and in every process
+    assert compile_cache.use_persistent_cache() == want
+    assert compile_cache.use_persistent_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()

@@ -438,6 +438,27 @@ def test_batch_failure_isolates_poisoned_request():
         service.close(drain=False)
 
 
+def test_batch_failure_is_logged_with_cause(caplog):
+    """A failed batch is logged with its exception and traceback, not
+    only counted: the lone re-runs that follow can all succeed."""
+    clock = ManualClock()
+    service = SimService(StubBackend(fail_batch_seeds={1}), clock=clock,
+                         config=ServeConfig(batch_size=2,
+                                            flush_interval_s=0.05))
+    try:
+        with caplog.at_level("ERROR", logger="repro.serve"):
+            futs = [service.submit(stub_request(s)) for s in (0, 1)]
+            for f in futs:
+                f.exception(timeout=WAIT)
+        (rec,) = [r for r in caplog.records if r.name == "repro.serve"]
+        assert "re-running each request alone" in rec.getMessage()
+        assert "RuntimeError: batch poisoned" in rec.getMessage()
+        assert rec.exc_info is not None
+        assert service.metrics()["isolated_retries"] == 2
+    finally:
+        service.close(drain=False)
+
+
 def test_real_backend_batch_failure_isolates(monkeypatch):
     """Same contract on a real jax backend: monkeypatched run_many
     raises mid-batch, healthy requests still resolve via run()."""

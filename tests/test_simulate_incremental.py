@@ -191,9 +191,10 @@ def test_resolve_mode_and_canonicalize(monkeypatch):
     monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
     assert dispatch.resolve_mode() in dispatch.MODES
     assert dispatch.resolve_mode("xla") == "xla"
-    # requesting compiled pallas off-TPU falls back to interpret
+    # requesting compiled pallas off-TPU raises: no silent interpret
     if jax.default_backend() != "tpu":
-        assert dispatch.resolve_mode("pallas") == "interpret"
+        with pytest.raises(ValueError, match="needs a TPU"):
+            dispatch.resolve_mode("pallas")
     monkeypatch.setenv(dispatch.ENV_VAR, "interpret")
     # env fills in the default (None) but never re-routes a pinned mode —
     # a backend's construction-time pin must match what later executes
@@ -205,6 +206,37 @@ def test_resolve_mode_and_canonicalize(monkeypatch):
     monkeypatch.setenv(dispatch.ENV_VAR, "bogus")
     with pytest.raises(ValueError):
         dispatch.resolve_mode()
+
+
+@pytest.mark.parametrize("platform", ["cpu", "gpu"])
+@pytest.mark.parametrize("via", ["request", "env", "config"])
+def test_pallas_off_tpu_raises(monkeypatch, platform, via):
+    """Compiled Pallas requested on any non-TPU platform is an error —
+    whether pinned in code, through REPRO_KERNELS or on an M4Config — and
+    nothing is counted as dispatched."""
+    from repro.obs.registry import get_registry, labeled
+    monkeypatch.setattr(dispatch, "_platform", lambda: platform)
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    reg = get_registry()
+    before = reg.snapshot()["counters"]
+    with pytest.raises(ValueError, match="needs a TPU"):
+        if via == "request":
+            dispatch.resolve_mode("pallas")
+        elif via == "env":
+            monkeypatch.setenv(dispatch.ENV_VAR, "pallas")
+            dispatch.resolve_mode()
+        else:
+            dispatch.canonicalize_cfg(M4Config(kernel_mode="pallas"))
+    monkeypatch.delenv(dispatch.ENV_VAR, raising=False)
+    after = reg.snapshot()["counters"]
+    for mode in dispatch.MODES:
+        name = labeled("kernels.dispatch", mode=mode)
+        assert after.get(name, 0) == before.get(name, 0)
+    # the platform probe itself still picks the right default
+    assert dispatch.resolve_mode() == "xla"
+    monkeypatch.setattr(dispatch, "_platform", lambda: "tpu")
+    assert dispatch.resolve_mode() == "pallas"
+    assert dispatch.resolve_mode("pallas") == "pallas"
 
 
 def test_fingerprints_include_kernel_mode(tiny_params, monkeypatch):
