@@ -27,6 +27,13 @@ GRU advances and GNN rounds execute through `repro.kernels.dispatch`
 (Pallas on TPU, jnp elsewhere, REPRO_KERNELS override); entry points pin
 the resolved mode into `cfg.kernel_mode` so it is part of the jit key.
 
+Each layer of the event step is a `jax.named_scope` — `m4.departure`,
+`m4.snapshot`, `m4.temporal`, `m4.spatial`, `m4.heads`, `m4.scatter` —
+so a profiler trace attributes every device op to its layer (the scopes
+change op metadata only, not the compiled program). The entry points
+open `repro.obs` spans, `m4.run` (`m4.run_many`) > `m4.build`, `m4.scan`,
+`m4.result`, which reach the profiler's trace while it collects.
+
 Prefer the unified entry point `repro.sim.get_backend("m4")` over calling
 these functions directly.
 """
@@ -43,6 +50,7 @@ import numpy as np
 
 from ..kernels.dispatch import canonicalize_cfg
 from ..nn import mlp
+from ..obs.trace import get_tracer
 from .model import (MATMUL_PRECISION, M4Config, predict_queue,
                     predict_size, predict_sldn, spatial_update,
                     temporal_update)
@@ -184,75 +192,87 @@ def make_event_step(cfg: M4Config, static, num_links: int,
         flow_links = static["flow_links"]
         cfg_vec = static["cfg_vec"]
         N = flow_links.shape[0]
-        if legacy:
-            active = (state["arrived"] & ~state["done"])[:N]
-            active = active.at[fid].set(True)  # arriving flow counts
-            snap_f, sfm = _build_snapshot_dense(cfg, flow_links, fid, active)
-        else:
-            snap_f, sfm = _build_snapshot(cfg, static, state["link_occ"], fid)
-            # occupancy arenas: the event flow enters (arrival) / leaves
-            # (departure) the membership slots of its own links — O(P)
-            state["link_occ"] = state["link_occ"].at[
-                static["occ_rows"][fid],
-                static["occ_slots"][fid]].set(is_arrival)
-        fgather = jnp.minimum(snap_f, N - 1)   # clamped gathers (masked out)
-        snap_l, slm, edge_l, edge_mask = _build_links(
-            cfg, flow_links, fgather, sfm, num_links, legacy=legacy)
-        sl_safe = jnp.minimum(snap_l, num_links)  # dump row = num_links
-        lgather = jnp.minimum(snap_l, num_links - 1)
+        with jax.named_scope("m4.snapshot"):
+            if legacy:
+                active = (state["arrived"] & ~state["done"])[:N]
+                active = active.at[fid].set(True)  # arriving flow counts
+                snap_f, sfm = _build_snapshot_dense(cfg, flow_links, fid,
+                                                    active)
+            else:
+                snap_f, sfm = _build_snapshot(cfg, static, state["link_occ"],
+                                              fid)
+                # occupancy arenas: the event flow enters (arrival) /
+                # leaves (departure) the membership slots of its own
+                # links — O(P)
+                state["link_occ"] = state["link_occ"].at[
+                    static["occ_rows"][fid],
+                    static["occ_slots"][fid]].set(is_arrival)
+            fgather = jnp.minimum(snap_f, N - 1)  # clamped (masked out)
+            snap_l, slm, edge_l, edge_mask = _build_links(
+                cfg, flow_links, fgather, sfm, num_links, legacy=legacy)
+            sl_safe = jnp.minimum(snap_l, num_links)  # dump row = num_links
+            lgather = jnp.minimum(snap_l, num_links - 1)
 
-        f_h = state["flow_h"][snap_f]
-        l_h = state["link_h"][sl_safe]
-        f_feat = static["flow_feat"][fgather]
-        l_feat = static["link_feat"][lgather]
+            f_h = state["flow_h"][snap_f]
+            l_h = state["link_h"][sl_safe]
+            f_feat = static["flow_feat"][fgather]
+            l_feat = static["link_feat"][lgather]
 
-        # arrival: init slot-0 hidden state from static features (§3.2.1)
-        fin = jnp.concatenate([static["flow_feat"][fid], cfg_vec], -1)
-        h_new = jnp.tanh(mlp(params["flow_init"], fin))
-        f_h = f_h.at[0].set(jnp.where(is_arrival, h_new, f_h[0]))
+            dt_f = t_ev - state["flow_last"][snap_f]
+            dt_f = dt_f.at[0].set(jnp.where(is_arrival, 0.0, dt_f[0]))
+            dt_l = t_ev - state["link_last"][sl_safe]
 
-        dt_f = t_ev - state["flow_last"][snap_f]
-        dt_f = dt_f.at[0].set(jnp.where(is_arrival, 0.0, dt_f[0]))
-        dt_l = t_ev - state["link_last"][sl_safe]
+        with jax.named_scope("m4.heads"):
+            # arrival: init slot-0 hidden state from static features (§3.2.1)
+            fin = jnp.concatenate([static["flow_feat"][fid], cfg_vec], -1)
+            h_new = jnp.tanh(mlp(params["flow_init"], fin))
+            f_h = f_h.at[0].set(jnp.where(is_arrival, h_new, f_h[0]))
 
-        f_h, l_h = temporal_update(params, cfg, f_h, l_h, dt_f, dt_l,
-                                   f_feat, l_feat, cfg_vec, ref_impl=legacy)
-        f_h2, l_h2 = spatial_update(params, cfg, f_h, l_h, edge_f, edge_l,
-                                    edge_mask, cfg_vec, ref_impl=legacy)
-        sldn = predict_sldn(params, f_h2, static["flow_feat"][fgather, 1] * 8.0,
-                            cfg_vec)
+        with jax.named_scope("m4.temporal"):
+            f_h, l_h = temporal_update(params, cfg, f_h, l_h, dt_f, dt_l,
+                                       f_feat, l_feat, cfg_vec,
+                                       ref_impl=legacy)
+        with jax.named_scope("m4.spatial"):
+            f_h2, l_h2 = spatial_update(params, cfg, f_h, l_h, edge_f,
+                                        edge_l, edge_mask, cfg_vec,
+                                        ref_impl=legacy)
+        with jax.named_scope("m4.heads"):
+            sldn = predict_sldn(params, f_h2,
+                                static["flow_feat"][fgather, 1] * 8.0,
+                                cfg_vec)
+            # departure-time re-prediction for snapshot flows
+            t_dep_new = (state["t_arr"][snap_f]
+                         + sldn * static["ideal_fct"][fgather])
+            t_dep_new = jnp.maximum(t_dep_new, t_ev + 1e-9)
 
-        # departure-time re-prediction for snapshot flows
-        t_dep_new = state["t_arr"][snap_f] + sldn * static["ideal_fct"][fgather]
-        t_dep_new = jnp.maximum(t_dep_new, t_ev + 1e-9)
-
-        if legacy:
-            # seed-style blend scatter: read-modify-write of the arenas
-            wf = sfm[:, None]
-            state["flow_h"] = state["flow_h"].at[snap_f].set(
-                wf * f_h2 + (1 - wf) * state["flow_h"][snap_f])
-            wl = (slm[:, None])
-            state["link_h"] = state["link_h"].at[sl_safe].set(
-                wl * l_h2 + (1 - wl) * state["link_h"][sl_safe])
-            state["flow_last"] = state["flow_last"].at[snap_f].set(
-                jnp.where(sfm > 0, t_ev, state["flow_last"][snap_f]))
-            state["link_last"] = state["link_last"].at[sl_safe].set(
-                jnp.where(slm > 0, t_ev, state["link_last"][sl_safe]))
-            state["t_dep"] = state["t_dep"].at[snap_f].set(
-                jnp.where(sfm > 0, t_dep_new, state["t_dep"][snap_f]))
-        else:
-            # scatter back with masked slots *redirected to the dump row*
-            # (index N / num_links) instead of blending old values back in —
-            # live rows receive exactly f_h2/l_h2, the dump row absorbs the
-            # rest, and the arenas update without a read-modify-write of
-            # the whole (N, H) buffer
-            idx_f = jnp.where(sfm > 0, snap_f, N)
-            idx_l = jnp.where(slm > 0, sl_safe, num_links)
-            state["flow_h"] = state["flow_h"].at[idx_f].set(f_h2)
-            state["link_h"] = state["link_h"].at[idx_l].set(l_h2)
-            state["flow_last"] = state["flow_last"].at[idx_f].set(t_ev)
-            state["link_last"] = state["link_last"].at[idx_l].set(t_ev)
-            state["t_dep"] = state["t_dep"].at[idx_f].set(t_dep_new)
+        with jax.named_scope("m4.scatter"):
+            if legacy:
+                # seed-style blend scatter: read-modify-write of the arenas
+                wf = sfm[:, None]
+                state["flow_h"] = state["flow_h"].at[snap_f].set(
+                    wf * f_h2 + (1 - wf) * state["flow_h"][snap_f])
+                wl = (slm[:, None])
+                state["link_h"] = state["link_h"].at[sl_safe].set(
+                    wl * l_h2 + (1 - wl) * state["link_h"][sl_safe])
+                state["flow_last"] = state["flow_last"].at[snap_f].set(
+                    jnp.where(sfm > 0, t_ev, state["flow_last"][snap_f]))
+                state["link_last"] = state["link_last"].at[sl_safe].set(
+                    jnp.where(slm > 0, t_ev, state["link_last"][sl_safe]))
+                state["t_dep"] = state["t_dep"].at[snap_f].set(
+                    jnp.where(sfm > 0, t_dep_new, state["t_dep"][snap_f]))
+            else:
+                # scatter back with masked slots *redirected to the dump
+                # row* (index N / num_links) instead of blending old values
+                # back in — live rows receive exactly f_h2/l_h2, the dump
+                # row absorbs the rest, and the arenas update without a
+                # read-modify-write of the whole (N, H) buffer
+                idx_f = jnp.where(sfm > 0, snap_f, N)
+                idx_l = jnp.where(slm > 0, sl_safe, num_links)
+                state["flow_h"] = state["flow_h"].at[idx_f].set(f_h2)
+                state["link_h"] = state["link_h"].at[idx_l].set(l_h2)
+                state["flow_last"] = state["flow_last"].at[idx_f].set(t_ev)
+                state["link_last"] = state["link_last"].at[idx_l].set(t_ev)
+                state["t_dep"] = state["t_dep"].at[idx_f].set(t_dep_new)
         return state, sldn, (snap_f, sfm)
 
     return event_step
@@ -326,44 +346,49 @@ def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
 
     def body(carry, _):
         state, ptr, t = carry
-        next_arr = jnp.where(ptr < N, arr_times[jnp.minimum(ptr, N - 1)], BIG)
-        if legacy:
-            dep_t = jnp.where(state["arrived"] & ~state["done"],
-                              state["t_dep"], BIG)[:N]
-        else:
-            # invariant: t_dep rows < N are finite exactly for flows that
-            # are arrived-and-not-done (init BIG, arrival/snapshot updates
-            # touch only active rows, departure resets to BIG), so the
-            # departure race reads the carry directly — no mask gathers
-            dep_t = state["t_dep"][:N]
-        dep_i = jnp.argmin(dep_t)
-        next_dep = dep_t[dep_i]
-        is_arr = next_arr <= next_dep
-        t_ev = jnp.where(is_arr, next_arr, next_dep)
-        fid = jnp.where(is_arr, arr_order[jnp.minimum(ptr, N - 1)], dep_i)
+        with jax.named_scope("m4.departure"):
+            next_arr = jnp.where(ptr < N, arr_times[jnp.minimum(ptr, N - 1)],
+                                 BIG)
+            if legacy:
+                dep_t = jnp.where(state["arrived"] & ~state["done"],
+                                  state["t_dep"], BIG)[:N]
+            else:
+                # invariant: t_dep rows < N are finite exactly for flows
+                # that are arrived-and-not-done (init BIG, arrival/snapshot
+                # updates touch only active rows, departure resets to BIG),
+                # so the departure race reads the carry directly — no mask
+                # gathers
+                dep_t = state["t_dep"][:N]
+            dep_i = jnp.argmin(dep_t)
+            next_dep = dep_t[dep_i]
+            is_arr = next_arr <= next_dep
+            t_ev = jnp.where(is_arr, next_arr, next_dep)
+            fid = jnp.where(is_arr, arr_order[jnp.minimum(ptr, N - 1)], dep_i)
 
         state, _, _ = step(params, state, t_ev, fid, is_arr)
-        if legacy:
-            state["arrived"] = state["arrived"].at[fid].set(
-                state["arrived"][fid] | is_arr)
-            state["done"] = state["done"].at[fid].set(
-                state["done"][fid] | ~is_arr)
-            state["fct"] = state["fct"].at[fid].set(
-                jnp.where(is_arr, state["fct"][fid],
-                          t_ev - state["t_arr"][fid]))
-            state["t_dep"] = state["t_dep"].at[fid].set(
-                jnp.where(is_arr, state["t_dep"][fid], BIG))
-        else:
-            # every event at fid implies "arrived"; "done" iff departure —
-            # plain sets, no read-modify-write; arrival-event writes of
-            # fct / t_dep redirect to the dump row instead of blending
-            fid_or_dump = jnp.where(is_arr, N, fid)
-            state["arrived"] = state["arrived"].at[fid].set(True)
-            state["done"] = state["done"].at[fid].set(~is_arr)
-            state["fct"] = state["fct"].at[fid_or_dump].set(
-                t_ev - state["t_arr"][fid])
-            state["t_dep"] = state["t_dep"].at[fid_or_dump].set(BIG)
-        ptr = ptr + is_arr.astype(jnp.int32)
+        with jax.named_scope("m4.scatter"):
+            if legacy:
+                state["arrived"] = state["arrived"].at[fid].set(
+                    state["arrived"][fid] | is_arr)
+                state["done"] = state["done"].at[fid].set(
+                    state["done"][fid] | ~is_arr)
+                state["fct"] = state["fct"].at[fid].set(
+                    jnp.where(is_arr, state["fct"][fid],
+                              t_ev - state["t_arr"][fid]))
+                state["t_dep"] = state["t_dep"].at[fid].set(
+                    jnp.where(is_arr, state["t_dep"][fid], BIG))
+            else:
+                # every event at fid implies "arrived"; "done" iff
+                # departure — plain sets, no read-modify-write; arrival-
+                # event writes of fct / t_dep redirect to the dump row
+                # instead of blending
+                fid_or_dump = jnp.where(is_arr, N, fid)
+                state["arrived"] = state["arrived"].at[fid].set(True)
+                state["done"] = state["done"].at[fid].set(~is_arr)
+                state["fct"] = state["fct"].at[fid_or_dump].set(
+                    t_ev - state["t_arr"][fid])
+                state["t_dep"] = state["t_dep"].at[fid_or_dump].set(BIG)
+            ptr = ptr + is_arr.astype(jnp.int32)
         return (state, ptr, t_ev), None
 
     length = 2 * N if num_events is None else num_events
@@ -434,11 +459,7 @@ def _open_loop_scan_sharded(params, cfg: M4Config, num_links: int, static,
 class M4Result:
     fcts: np.ndarray
     slowdowns: np.ndarray
-    wallclock: float          # steady-state execution wall time
-    # wall time of the cold first call (XLA trace + compile + run); 0.0
-    # unless the entry point ran a warmup call to split the two — without
-    # it, `wallclock` on a fresh shape is dominated by compilation.
-    compile_wall: float = 0.0
+    wallclock: float          # the scan's wall time, dispatch to ready
     # finalized `repro.obs.timeseries/1` dict when a ProbeConfig was passed
     probes: object = None
 
@@ -558,45 +579,44 @@ def _arrival_order(static):
 
 
 def simulate_open_loop(params, cfg: M4Config, topo, net_config, flows, *,
-                       warmup=False, snapshot_impl="incremental",
+                       snapshot_impl="incremental",
                        probes: ProbeConfig = None) -> M4Result:
     """One scenario through the open-loop scan.
 
-    `warmup=True` runs the scan twice and reports the cold first call
-    (trace + compile + run) as `M4Result.compile_wall`, keeping `wallclock`
-    steady-state. `snapshot_impl="dense"` switches to the reference
-    builder (tests/benchmark comparisons only). `probes` (a static
-    `ProbeConfig`) additionally records intermediate-state time series
-    into `M4Result.probes`; None compiles the identical probe-free
-    program."""
-    cfg = canonicalize_cfg(cfg)
-    probes = normalize_probes(probes, M4_CHANNELS)
-    static, num_links, ideal = make_static(topo, flows, net_config, cfg)
-    order, times = _arrival_order(static)
-    args = (params, cfg, num_links, static, jnp.asarray(order),
-            jnp.asarray(times))
-    compile_wall = 0.0
-    if warmup:
-        t0 = time.perf_counter()
-        jax.block_until_ready(
-            _open_loop_scan(*args, snapshot_impl=snapshot_impl,
-                            probes=probes))
-        compile_wall = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    out = _open_loop_scan(*args, snapshot_impl=snapshot_impl, probes=probes)
-    out = jax.block_until_ready(out)
-    wall = time.perf_counter() - t0
-    series = None
-    if probes is None:
-        fct, done = out
-    else:
-        fct, done, bufs = out
-        series = _finalize_m4_series(probes, bufs, flows,
-                                     num_flows=len(flows),
-                                     num_links=num_links)
-    fct = np.asarray(fct)
-    return M4Result(fcts=fct, slowdowns=fct / ideal, wallclock=wall,
-                    compile_wall=compile_wall, probes=series)
+    `snapshot_impl="dense"` switches to the reference builder
+    (tests/benchmark comparisons only). `probes` (a static `ProbeConfig`)
+    additionally records intermediate-state time series into
+    `M4Result.probes`; None compiles the identical probe-free program.
+    The call is the `m4.run` span, with the children `m4.build`,
+    `m4.scan` and `m4.result` (`repro.obs`)."""
+    tracer = get_tracer()
+    with tracer.span("m4.run"):
+        cfg = canonicalize_cfg(cfg)
+        probes = normalize_probes(probes, M4_CHANNELS)
+        with tracer.span("m4.build"):
+            static, num_links, ideal = make_static(topo, flows, net_config,
+                                                   cfg)
+            order, times = _arrival_order(static)
+            args = (params, cfg, num_links, static, jnp.asarray(order),
+                    jnp.asarray(times))
+        with tracer.span("m4.scan"):
+            t0 = time.perf_counter()
+            out = _open_loop_scan(*args, snapshot_impl=snapshot_impl,
+                                  probes=probes)
+            out = jax.block_until_ready(out)
+            wall = time.perf_counter() - t0
+        with tracer.span("m4.result"):
+            series = None
+            if probes is None:
+                fct, done = out
+            else:
+                fct, done, bufs = out
+                series = _finalize_m4_series(probes, bufs, flows,
+                                             num_flows=len(flows),
+                                             num_links=num_links)
+            fct = np.asarray(fct)
+            return M4Result(fcts=fct, slowdowns=fct / ideal, wallclock=wall,
+                            probes=series)
 
 
 def stack_scenarios(cfg: M4Config, scenarios):
@@ -636,50 +656,58 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
     and trimmed per scenario on the host); the multi-device sharded path
     is probe-free, so probed batches stay on the vmapped path.
     """
-    cfg = canonicalize_cfg(cfg)
-    probes = normalize_probes(probes, M4_CHANNELS)
     scenarios = list(scenarios)
     if not scenarios:
         return []
-    n_max = max(len(flows) for _, _, flows in scenarios)
-    batched, order_b, times_b, l_max, ideals = stack_scenarios(cfg,
-                                                               scenarios)
-    counts = [len(flows) for _, _, flows in scenarios]
-    D = jax.local_device_count()
-    t0 = time.perf_counter()
-    bufs = None
-    if (D > 1 and len(scenarios) >= D and snapshot_impl == "incremental"
-            and probes is None):
-        from .sharding import shard_leaves, unshard
-        fct, done = _open_loop_scan_sharded(
-            params, cfg, l_max, shard_leaves(batched, D),
-            shard_leaves(order_b, D), shard_leaves(times_b, D))
-        fct = unshard(np.asarray(jax.block_until_ready(fct)),
-                      len(scenarios))
-    else:
-        res = _open_loop_scan_batched(
-            params, cfg, l_max, batched, order_b, times_b,
-            snapshot_impl=snapshot_impl, probes=probes)
-        res = jax.block_until_ready(res)
-        if probes is None:
-            fct, done = res
-        else:
-            fct, done, bufs = res
-        fct = np.asarray(fct)
-    wall = time.perf_counter() - t0
-    out = []
-    for b, n in enumerate(counts):
-        f = fct[b, :n]
-        series = None
-        if bufs is not None:
-            topo_b, _, flows_b = scenarios[b]
-            series = _finalize_m4_series(
-                probes, {k: v[b] for k, v in bufs.items()}, flows_b,
-                num_flows=n_max, num_links=l_max,
-                trim_links=topo_b.num_links)
-        out.append(M4Result(fcts=f, slowdowns=f / ideals[b][:n],
-                            wallclock=wall / len(scenarios), probes=series))
-    return out
+    tracer = get_tracer()
+    with tracer.span("m4.run_many"):
+        cfg = canonicalize_cfg(cfg)
+        probes = normalize_probes(probes, M4_CHANNELS)
+        n_max = max(len(flows) for _, _, flows in scenarios)
+        with tracer.span("m4.build"):
+            batched, order_b, times_b, l_max, ideals = stack_scenarios(
+                cfg, scenarios)
+        counts = [len(flows) for _, _, flows in scenarios]
+        D = jax.local_device_count()
+        sharded = (D > 1 and len(scenarios) >= D
+                   and snapshot_impl == "incremental" and probes is None)
+        bufs = None
+        with tracer.span("m4.scan"):
+            t0 = time.perf_counter()
+            if sharded:
+                from .sharding import shard_leaves
+                res = _open_loop_scan_sharded(
+                    params, cfg, l_max, shard_leaves(batched, D),
+                    shard_leaves(order_b, D), shard_leaves(times_b, D))
+            else:
+                res = _open_loop_scan_batched(
+                    params, cfg, l_max, batched, order_b, times_b,
+                    snapshot_impl=snapshot_impl, probes=probes)
+            res = jax.block_until_ready(res)
+            wall = time.perf_counter() - t0
+        with tracer.span("m4.result"):
+            if probes is None:
+                fct, done = res
+            else:
+                fct, done, bufs = res
+            fct = np.asarray(fct)
+            if sharded:
+                from .sharding import unshard
+                fct = unshard(fct, len(scenarios))
+            out = []
+            for b, n in enumerate(counts):
+                f = fct[b, :n]
+                series = None
+                if bufs is not None:
+                    topo_b, _, flows_b = scenarios[b]
+                    series = _finalize_m4_series(
+                        probes, {k: v[b] for k, v in bufs.items()}, flows_b,
+                        num_flows=n_max, num_links=l_max,
+                        trim_links=topo_b.num_links)
+                out.append(M4Result(fcts=f, slowdowns=f / ideals[b][:n],
+                                    wallclock=wall / len(scenarios),
+                                    probes=series))
+            return out
 
 
 @partial(jax.jit, static_argnums=(3,))

@@ -269,9 +269,12 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
         if pid in {p.pid for p in procs.values()} and _pid_alive(pid):
             os.kill(pid, signal.SIGKILL)
             metrics.kills += 1
+        # park the task (err marker) before freeing its lease: a free
+        # lease with no marker is claimable, and a worker that claims and
+        # finishes it first would hide the retry from this loop
+        coord.synthetic_error(tid, owner, why)
         coord.leases.release(tid)
         metrics.lease_breaks += 1
-        coord.synthetic_error(tid, owner, why)
         say(f"reaped {tid[:12]} ({why})")
 
     attempts: Dict[str, int] = {}
@@ -380,11 +383,11 @@ def run_fleet(tasks: List[Task], job: FleetJob, config: FleetConfig,
                         info = coord.leases.owner(tid) or {}
                         if (info.get("owner") == f"w{idx}"
                                 and tid in pending):
-                            coord.leases.release(tid)
-                            metrics.lease_breaks += 1
                             coord.synthetic_error(
                                 tid, f"w{idx}",
                                 f"worker exited {code} mid-chunk")
+                            coord.leases.release(tid)
+                            metrics.lease_breaks += 1
 
             # ---- keep the pool full while work remains
             while pending and len(procs) < min(config.workers,

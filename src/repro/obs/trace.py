@@ -25,6 +25,11 @@ successful attempt completes it.
 Tracing is opt-in.  With no trace dir configured the tracer hands out a
 shared no-op span; the hot serve path does no I/O, no id generation,
 and no timestamping when tracing is off.
+
+While a JAX profiler session is collecting, a span opened with
+:meth:`Tracer.span` also appears in the profiler's trace under its own
+name (``jax.profiler.TraceAnnotation``), on the clock of the device
+events, whether or not a trace dir is configured.
 """
 
 from __future__ import annotations
@@ -32,6 +37,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -55,7 +61,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id",
-        "t_start", "t_end", "attrs", "status", "_tracer", "_pop",
+        "t_start", "t_end", "attrs", "status", "_tracer", "_pop", "_ann",
     )
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
@@ -71,6 +77,7 @@ class Span:
         self.attrs: Dict[str, object] = dict(attrs or {})
         self.status = "ok"
         self._pop = False
+        self._ann = None
 
     def attr(self, key: str, value: object) -> "Span":
         self.attrs[key] = value
@@ -80,6 +87,8 @@ class Span:
         if self.t_end is not None:  # idempotent
             return
         self.t_end = time.time()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
         if status is not None:
             self.status = status
         if attrs:
@@ -141,6 +150,36 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+def _profiler_annotation(name: str):
+    """An open ``TraceAnnotation`` named ``name`` while a JAX profiler
+    session is collecting, else None. Never imports jax: a process
+    that has not loaded it has no profiler session."""
+    prof = sys.modules.get("jaxlib._profiler")
+    if prof is None or not prof.TraceMe.is_enabled():
+        return None
+    ann = prof.TraceMe(name)
+    ann.__enter__()
+    return ann
+
+
+class _ProfilerSpan(_NullSpan):
+    """A span that only the profiler's trace records: tracing off, a
+    profiler session collecting."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann) -> None:
+        self._ann = ann
+
+    def end(self, status=None, **attrs):
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+
+    def __exit__(self, exc_type, exc, tb):
+        self.end()
+
+
 class Tracer:
     """Span factory bound to one output directory (or disabled)."""
 
@@ -192,11 +231,15 @@ class Tracer:
              attrs: Optional[dict] = None):
         """Create a span and push it on the thread-local stack, so
         spans opened inside it become its children.  Use as a context
-        manager."""
+        manager, and end it on the thread that opened it: while a JAX
+        profiler session collects, it is also an annotation of that
+        thread in the profiler's trace."""
+        ann = _profiler_annotation(name)
         if not self.enabled:
-            return NULL_SPAN
+            return NULL_SPAN if ann is None else _ProfilerSpan(ann)
         sp = self._make(name, parent, trace_id, span_id, attrs)
         sp._pop = True
+        sp._ann = ann
         self._stack().append(sp)
         return sp
 

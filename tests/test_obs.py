@@ -7,6 +7,10 @@
 - the tracer: thread-local nesting, JSONL persistence, idempotent end,
   cross-process parent propagation via env, and a shared no-op span
   when tracing is off (the warm serve path does zero telemetry work);
+- spans on the profiler's clock: under a `jax.profiler` session m4's
+  `m4.run` > `m4.build`/`m4.scan`/`m4.result` spans are host events of
+  the trace, with or without a trace dir; with a trace dir they also
+  reach the JSONL and pass `--check`;
 - serve integration: one cache-miss request reconstructs as a single
   trace (admit -> queue -> flush -> compile/run), `/metrics` exposes
   per-lane queue gauges in both JSON and Prometheus form;
@@ -263,6 +267,107 @@ def test_torn_trailing_line_is_skipped(trace_dir):
     with open(path, "a", encoding="utf-8") as fh:
         fh.write('{"trace_id": "deadbeef", "name": "torn')  # killed writer
     assert [r["name"] for r in read_spans(trace_dir)] == ["ok"]
+
+
+# ------------------------------------------- spans on the profiler's clock
+M4_CHILDREN = ("m4.build", "m4.scan", "m4.result")
+
+
+def _host_events(trace_root):
+    """(name, start_ns, end_ns) of the host events of a profiler trace."""
+    import glob
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_root, "**", "*.xplane.pb"),
+                        recursive=True)
+    return [(ev.name, ev.start_ns, ev.end_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def _profiled(tmp_path, fn):
+    import jax
+    root = str(tmp_path / "profile")
+    jax.profiler.start_trace(root)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(root)
+
+
+@pytest.fixture(scope="module")
+def tiny_m4():
+    """A tiny m4 and two small scenarios; `run(batched)` simulates them."""
+    import jax
+    from repro.core import simulate as sim
+    from repro.core.model import M4Config, init_m4
+    cfg = M4Config(hidden=16, gnn_dim=12, mlp_hidden=8, gnn_layers=2,
+                   snap_flows=8, snap_links=24, kernel_mode="xla")
+    params = init_m4(jax.random.PRNGKey(0), cfg)
+    scens = [ScenarioSpec(topo="ft-4x2x2", num_flows=10, seed=s)
+             .to_scenario() for s in (3, 4)]
+    scens = [(sc.topo, sc.config, sc.generate()) for sc in scens]
+
+    def run(batched):
+        if batched:
+            return sim.simulate_open_loop_batch(params, cfg, scens)
+        return sim.simulate_open_loop(params, cfg, *scens[0])
+
+    run(False), run(True)                   # compile outside the traces
+    return run
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_m4_spans_nest_in_the_profiler_trace(tmp_path, monkeypatch, tiny_m4,
+                                             batched):
+    monkeypatch.delenv("REPRO_TRACE_DIR", raising=False)
+    configure(None)
+    events = _profiled(tmp_path, lambda: tiny_m4(batched))
+    root = "m4.run_many" if batched else "m4.run"
+    (run,) = [e for e in events if e[0] == root]
+    kids = {e[0]: e for e in events if e[0] in M4_CHILDREN}
+    assert sorted(kids) == sorted(M4_CHILDREN)
+    for _, start, end in kids.values():
+        assert run[1] <= start <= end <= run[2]
+    assert kids["m4.build"][2] <= kids["m4.scan"][1]
+    assert kids["m4.scan"][2] <= kids["m4.result"][1]
+    assert read_spans(str(tmp_path)) == []  # no trace dir: no JSONL
+
+
+def test_m4_spans_reach_the_jsonl_and_pass_check(trace_dir, tmp_path,
+                                                 tiny_m4, capsys):
+    events = _profiled(tmp_path, lambda: tiny_m4(False))
+    recs = read_spans(trace_dir)
+    by_name = {r["name"]: r for r in recs}
+    assert sorted(by_name) == sorted(("m4.run",) + M4_CHILDREN)
+    for name in M4_CHILDREN:
+        assert by_name[name]["parent_id"] == by_name["m4.run"]["span_id"]
+    # the same spans are host events of the profiler's trace
+    assert {e[0] for e in events} >= set(by_name)
+    assert obs_cli.main(["--dir", trace_dir, "--check"]) == 0
+    assert "obs check: OK" in capsys.readouterr().out
+
+
+def test_span_is_null_without_profiler_or_trace_dir(tmp_path, monkeypatch):
+    from repro.obs import phase
+    monkeypatch.delenv("REPRO_TRACE_DIR", raising=False)
+    tracer = configure(None)
+    assert tracer.span("m4.run") is NULL_SPAN
+    seen = []
+
+    def work():
+        sp = tracer.span("m4.run")       # a profiler session collects
+        seen.append(sp)
+        with sp:
+            with phase("demo", registry=MetricsRegistry()):
+                pass
+
+    events = _profiled(tmp_path, work)
+    assert seen[0] is not NULL_SPAN
+    assert {"m4.run", "phase:demo"} <= {e[0] for e in events}
+    assert tracer.span("m4.run") is NULL_SPAN       # the session is over
+    assert read_spans(str(tmp_path)) == []
 
 
 # ------------------------------------------------------------------- serve

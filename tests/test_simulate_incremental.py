@@ -9,7 +9,8 @@ Three layers of equivalence evidence:
   suite, batched, within rtol 1e-5;
 - kernel modes: the same FCTs under REPRO_KERNELS-style mode overrides
   ("xla" vs "interpret"), plus closed-loop/next_departure behavior and
-  the compile-vs-steady wallclock split.
+  the named scopes that mark the event step's layers in the compiled
+  program.
 """
 import os
 import sys
@@ -175,16 +176,39 @@ def test_closed_loop_occupancy_tracks_active(tiny_params):
     assert not occ[rows[live], slots[live]].any()
 
 
-# ------------------------------------------------------- wallclock / modes
-def test_warmup_splits_compile_from_steady(tiny_params):
+# ----------------------------------------------------- named scopes / modes
+SCOPES = ("m4.departure", "m4.snapshot", "m4.temporal", "m4.spatial",
+          "m4.heads", "m4.scatter")
+
+
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+@pytest.mark.parametrize("batched", [False, True])
+def test_event_step_scopes_in_compiled_program(tiny_params, mode, batched):
+    """Every layer of the event step is a named scope: the CPU-compiled
+    scan carries each scope in its ops' `op_name` metadata, which the
+    profiler reports as each device op's name stack."""
     import dataclasses
-    sc = dataclasses.replace(_spec(4), num_flows=23).to_scenario()
-    flows = sc.generate()        # distinctive arena shape -> fresh compile
-    r = sim.simulate_open_loop(tiny_params, TINY, sc.topo, sc.config,
-                               flows, warmup=True)
-    assert r.compile_wall > 0 and r.wallclock > 0
-    # the cold call includes trace+compile+run: it must dominate steady
-    assert r.compile_wall > r.wallclock
+    import re
+    cfg = dataclasses.replace(TINY, kernel_mode=mode)
+    scens = [_spec(s).to_scenario() for s in (1, 5)]
+    if batched:
+        static, order, times, L, _ = sim.stack_scenarios(
+            cfg, [(sc.topo, sc.config, sc.generate()) for sc in scens])
+        fn = sim._open_loop_scan_batched
+    else:
+        sc = scens[0]
+        static, L, _ = sim.make_static(sc.topo, sc.generate(), sc.config,
+                                       cfg)
+        order, times = sim._arrival_order(static)
+        fn = sim._open_loop_scan
+    hlo = fn.lower(tiny_params, cfg, L, static, order, times) \
+        .compile().as_text()
+    stacks = re.findall(r'op_name="([^"]*)"', hlo)
+    found = {c for st in stacks for c in st.split("/") if c in SCOPES}
+    assert found == set(SCOPES)
+    # the layers do not nest: an op belongs to one of them at most
+    assert not any(len(set(st.split("/")) & set(SCOPES)) > 1
+                   for st in stacks)
 
 
 def test_resolve_mode_and_canonicalize(monkeypatch):
