@@ -12,6 +12,8 @@ static XLA program. The GRU cells and GNN rounds execute through
 `repro.kernels.dispatch` — compiled Pallas kernels on TPU, the jnp
 reference path elsewhere, overridable with REPRO_KERNELS
 (`M4Config.kernel_mode` pins the resolved mode into the jit cache key).
+In the kernel modes they read the weights from the layout that
+`dispatch.stage_params` added to `params` once per call.
 """
 from __future__ import annotations
 
@@ -139,9 +141,10 @@ def gnn_forward(params, cfg: M4Config, f_h, l_h, edge_f, edge_l, edge_mask,
             f, l = _bipartite_round(layer, f, l, edge_f, edge_l, edge_mask,
                                     cfg.snap_links)
         return f, l
-    return dispatch.gnn_rounds(params["gnn"], f, l, edge_f, edge_l,
-                               edge_mask, cfg.snap_links,
-                               mode=dispatch.resolve_mode(cfg.kernel_mode))
+    mode = dispatch.resolve_mode(cfg.kernel_mode)
+    return dispatch.gnn_rounds(dispatch.kernel_params(params, mode)["gnn"],
+                               f, l, edge_f, edge_l, edge_mask,
+                               cfg.snap_links, mode=mode)
 
 
 # ---------------------------------------------------------------- queries
@@ -180,7 +183,8 @@ def temporal_update(params, cfg: M4Config, f_h, l_h, dt_f, dt_l,
         from ..nn.layers import gru_cell as gru_ref
         return (gru_ref(params["gru1"], xin_f, f_h),
                 gru_ref(params["gruA"], xin_l, l_h))
-    return dispatch.gru_cell_pair(params["gru1"], params["gruA"],
+    w = dispatch.kernel_params(params, mode)
+    return dispatch.gru_cell_pair(w["gru1"], w["gruA"],
                                   xin_f, f_h, xin_l, l_h, mode=mode)
 
 
@@ -199,7 +203,8 @@ def spatial_update(params, cfg: M4Config, f_h, l_h, edge_f, edge_l, edge_mask,
         f_new = gru_ref(params["gru2"], jnp.concatenate([gf, cf], -1), f_h)
         l_new = gru_ref(params["gruB"], jnp.concatenate([gl, cl], -1), l_h)
         return f_new, l_new
-    return dispatch.gru_cell_pair(params["gru2"], params["gruB"],
+    w = dispatch.kernel_params(params, mode)
+    return dispatch.gru_cell_pair(w["gru2"], w["gruB"],
                                   jnp.concatenate([gf, cf], -1), f_h,
                                   jnp.concatenate([gl, cl], -1), l_h,
                                   mode=mode)

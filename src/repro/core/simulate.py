@@ -26,6 +26,9 @@ so the carry is updated in place instead of copied every event.
 GRU advances and GNN rounds execute through `repro.kernels.dispatch`
 (Pallas on TPU, jnp elsewhere, REPRO_KERNELS override); entry points pin
 the resolved mode into `cfg.kernel_mode` so it is part of the jit key.
+The kernels' padded weight layout (`dispatch.stage_params`) is built
+once per call in `_open_loop_core`, before the scan (once per session in
+`M4Simulator`), and the event step hands it to the kernels as it is.
 
 Each layer of the event step is a `jax.named_scope` — `m4.departure`,
 `m4.snapshot`, `m4.temporal`, `m4.spatial`, `m4.heads`, `m4.scatter` —
@@ -48,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..kernels.dispatch import canonicalize_cfg
+from ..kernels.dispatch import canonicalize_cfg, resolve_mode, stage_params
 from ..nn import mlp
 from ..obs.trace import get_tracer
 from .model import (MATMUL_PRECISION, M4Config, predict_queue,
@@ -341,6 +344,9 @@ def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
                     probes=None):
     N = arr_times.shape[0]
     legacy = snapshot_impl == "dense"
+    # the kernels' weight layout, built here once and carried through the
+    # scan as loop-invariant values rather than rebuilt in every event
+    params = stage_params(params, resolve_mode(cfg.kernel_mode))
     step = make_event_step(cfg, static, num_links, snapshot_impl)
     state = init_sim_state(params, cfg, static, N, num_links)
 
@@ -734,7 +740,9 @@ class M4Simulator:
 
     def __init__(self, params, cfg: M4Config, topo, net_config, flows):
         cfg = canonicalize_cfg(cfg)
-        self.params, self.cfg = params, cfg
+        # kernel-layout weights once per session, not once per event
+        self.params = stage_params(params, cfg.kernel_mode)
+        self.cfg = cfg
         self.static, self.num_links, self.ideal = make_static(
             topo, flows, net_config, cfg)
         self.N = len(flows)

@@ -53,13 +53,13 @@ def _round_kernel(f_ref, l_ref, m_ref, wf_ref, wl_ref, bf_ref, bl_ref,
 def bipartite_round_pallas(f_emb, l_emb, m, wf, wl, bf, bl, *,
                            tile_g: int = 128, interpret: bool = True):
     """f_emb: (SF, G), l_emb: (SL, G), m: (SF, SL) incidence (float),
-    wf/wl: (2G, G), bf/bl: (G,). G must be a multiple of tile_g
+    wf/wl: (2G, G), bf/bl: (G,) or (1, G). G must be a multiple of tile_g
     (ops.py pads). Returns (f_new, l_new)."""
     SF, G = f_emb.shape
     SL = l_emb.shape[0]
     assert G % tile_g == 0, (G, tile_g)
     grid = (G // tile_g,)
-    bf2, bl2 = bf[None, :], bl[None, :]
+    bf2, bl2 = bf.reshape(1, -1), bl.reshape(1, -1)
 
     return pl.pallas_call(
         _round_kernel,

@@ -1,6 +1,12 @@
 """jit'd wrapper: padding to MXU-aligned shapes + multi-round driver used by
 `repro.core.model.gnn_forward` when `repro.kernels.dispatch` resolves to a
-Pallas mode ("pallas" on TPU, "interpret" elsewhere)."""
+Pallas mode ("pallas" on TPU, "interpret" elsewhere).
+
+Two steps: `stage_round` lays one round's weights out as the kernel takes
+them, and `bipartite_round_staged` pads the embeddings and calls the
+kernel. A scan that runs the rounds every step stages once, outside the
+loop (`repro.kernels.dispatch.stage_params`); `bipartite_round` does both
+per call."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -18,33 +24,47 @@ def _pad_to(x, mult, axis):
     return jnp.pad(x, widths)
 
 
-def bipartite_round(f_emb, l_emb, edge_f, edge_l, edge_mask, wf, wl, bf, bl,
-                    *, interpret=True):
-    """Drop-in replacement for ref.bipartite_round_ref via the Pallas kernel."""
+def stage_round(wf, wl, bf, bl):
+    """Kernel layout of one round's weights: wf/wl (2G, G) -> (2Gp, Gp)
+    with the [self; agg] halves at rows 0 and Gp, bf/bl (G,) -> (1, Gp),
+    G rounded up to 128, zeros elsewhere."""
+    G = wf.shape[1]
+    Gp = G + ((-G) % 128)
+
+    def halves(w):
+        wp = jnp.zeros((2 * Gp, Gp), w.dtype)
+        return wp.at[:G, :G].set(w[:G]).at[Gp:Gp + G, :G].set(w[G:])
+
+    return {"wf": halves(wf), "wl": halves(wl),
+            "bf": _pad_to(bf, 128, 0)[None], "bl": _pad_to(bl, 128, 0)[None]}
+
+
+def bipartite_round_staged(f_emb, l_emb, edge_f, edge_l, edge_mask, w, *,
+                           interpret=True):
+    """One round on weights staged by `stage_round`: pads the embeddings
+    (·, G) to the staged width, runs the kernel, slices back."""
     SF, G = f_emb.shape
     SL = l_emb.shape[0]
     m = incidence_from_edges(edge_f, edge_l, edge_mask, SF, SL)
-    Gp = G + ((-G) % 128)
     fp = _pad_to(f_emb, 128, 1)
     lp = _pad_to(l_emb, 128, 1)
-    # weights: (2G, G) -> (2Gp, Gp), keeping [self; agg] halves aligned
-    wfp = jnp.zeros((2 * Gp, Gp), wf.dtype)
-    wfp = wfp.at[:G, :G].set(wf[:G]).at[Gp:Gp + G, :G].set(wf[G:])
-    wlp = jnp.zeros((2 * Gp, Gp), wl.dtype)
-    wlp = wlp.at[:G, :G].set(wl[:G]).at[Gp:Gp + G, :G].set(wl[G:])
-    bfp = _pad_to(bf, 128, 0)
-    blp = _pad_to(bl, 128, 0)
-    fo, lo = bipartite_round_pallas(fp, lp, m, wfp, wlp, bfp, blp,
-                                    interpret=interpret)
+    fo, lo = bipartite_round_pallas(fp, lp, m, w["wf"], w["wl"], w["bf"],
+                                    w["bl"], interpret=interpret)
     return fo[:, :G], lo[:, :G]
 
 
-def bipartite_rounds(gnn_layers, f, l, edge_f, edge_l, edge_mask, *,
+def bipartite_round(f_emb, l_emb, edge_f, edge_l, edge_mask, wf, wl, bf, bl,
+                    *, interpret=True):
+    """Drop-in replacement for ref.bipartite_round_ref via the Pallas kernel."""
+    return bipartite_round_staged(f_emb, l_emb, edge_f, edge_l, edge_mask,
+                                  stage_round(wf, wl, bf, bl),
+                                  interpret=interpret)
+
+
+def bipartite_rounds(staged_layers, f, l, edge_f, edge_l, edge_mask, *,
                      interpret=True):
-    """Multi-round GNN used by m4's spatial model."""
-    for layer in gnn_layers:
-        f, l = bipartite_round(
-            f, l, edge_f, edge_l, edge_mask,
-            layer["wf"]["w"], layer["wl"]["w"],
-            layer["wf"]["b"], layer["wl"]["b"], interpret=interpret)
+    """Multi-round GNN used by m4's spatial model, on `stage_round`s."""
+    for w in staged_layers:
+        f, l = bipartite_round_staged(f, l, edge_f, edge_l, edge_mask, w,
+                                      interpret=interpret)
     return f, l

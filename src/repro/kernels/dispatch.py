@@ -33,6 +33,10 @@ threads the resolved mode as a static argument. Backend fingerprints
 (`repro.sim.backends`) include the resolved mode for the same reason:
 cached sweep results are only valid for the kernel path that produced
 them.
+
+The kernels take their weights padded to MXU-aligned widths.
+:func:`stage_params` builds that layout once per call, before an event
+scan, and the primitives below read it through :func:`kernel_params`.
 """
 from __future__ import annotations
 
@@ -95,17 +99,62 @@ def canonicalize_cfg(cfg):
     return dataclasses.replace(cfg, kernel_mode=resolve_mode(cfg.kernel_mode))
 
 
+# ------------------------------------------------------------ staging
+STAGED = "_kernel"
+GRUS = ("gru1", "gruA", "gru2", "gruB")
+
+
+def stage_params(params, mode: str):
+    """m4 params with the GRU and GNN weights also in the kernels' layout.
+
+    In "pallas" and "interpret" modes, returns `params` with one more
+    entry, ``params[STAGED]``: each of `GRUS` as `fused_gru.ops.stage_gru`
+    lays it out and "gnn" as a list of `bipartite.ops.stage_round`s. The
+    original entries stay. In "xla" mode, returns `params` unchanged.
+
+    The weights do not change inside an event scan, so its entry point
+    stages once, before the loop: the per-event step then hands the staged
+    arrays straight to the kernels (see `kernel_params`). Staging inside
+    the loop body would rebuild ~26 MB of padded weights every event at
+    the paper's widths; XLA does not hoist such padding out of a loop.
+    """
+    if mode == "xla":
+        return params
+    from ..obs.registry import get_registry, labeled
+    from .bipartite.ops import stage_round
+    from .fused_gru.ops import stage_gru
+    get_registry().inc(labeled("kernels.staged", mode=mode))
+    staged = {name: stage_gru(**params[name]) for name in GRUS}
+    staged["gnn"] = [stage_round(layer["wf"]["w"], layer["wl"]["w"],
+                                 layer["wf"]["b"], layer["wl"]["b"])
+                     for layer in params["gnn"]]
+    return {**params, STAGED: staged}
+
+
+def kernel_params(params, mode: str):
+    """The GRU and GNN weights as the primitives below take them in
+    `mode`: `params` in "xla" mode, else the tree `stage_params` added."""
+    if mode == "xla":
+        return params
+    if STAGED not in params:
+        raise ValueError(
+            f"kernel mode {mode!r} takes the weights staged once per call: "
+            "pass dispatch.stage_params(params, mode)")
+    return params[STAGED]
+
+
 # ------------------------------------------------------------- primitives
 def gru_cell(p, x, h, *, mode: str):
-    """GRU cell on params dict {"wi","wh","bi","bh"} (repro.nn layout)."""
+    """GRU cell on params dict {"wi","wh","bi","bh"}: the repro.nn layout
+    in "xla" mode, else the kernel layout of `stage_params`."""
     if mode == "xla":
         from ..nn.layers import gru_cell as gru_ref
         return gru_ref(p, x, h)
-    from .fused_gru.ops import gru_cell as gru_fused
+    from .fused_gru.ops import gru_cell_staged
     interp = mode != "pallas"
     # interpret mode lowers to XLA anyway — small tiles beat MXU alignment
-    return gru_fused(x, h, p["wi"], p["wh"], p["bi"], p["bh"],
-                     tile_b=8 if interp else 128, interpret=interp)
+    return gru_cell_staged(x, h, p, tile_b=8 if interp else 128,
+                           interpret=interp)
 
 
 def gru_cell_pair(p_f, p_l, x_f, h_f, x_l, h_l, *, mode: str):
@@ -116,7 +165,8 @@ def gru_cell_pair(p_f, p_l, x_f, h_f, x_l, h_l, *, mode: str):
     matrices, so XLA runs 2 GEMMs + one set of gate nonlinearities instead
     of 4 GEMMs + two — the event step is op-dispatch-bound on CPU, and the
     zero blocks change nothing numerically (x + 0·w = x). Pallas modes
-    keep the per-cell fused kernel (each cell is already one kernel call).
+    keep the per-cell fused kernel (each cell is already one kernel call),
+    on weights in the `stage_params` layout.
     """
     if mode != "xla":
         return (gru_cell(p_f, x_f, h_f, mode=mode),
@@ -152,7 +202,8 @@ def gru_cell_pair(p_f, p_l, x_f, h_f, x_l, h_l, *, mode: str):
 
 def gnn_rounds(layers, f, l, edge_f, edge_l, edge_mask, num_links, *,
                mode: str):
-    """Multi-round bipartite GraphSAGE (m4's spatial model)."""
+    """Multi-round bipartite GraphSAGE (m4's spatial model). `layers`
+    are `params["gnn"]` in "xla" mode, else their `stage_params` layout."""
     if mode == "xla":
         import jax.numpy as jnp
         from .bipartite.ref import bipartite_rounds_matmul

@@ -34,8 +34,9 @@ def _gru_kernel(x_ref, h_ref, wi_ref, wh_ref, bi_ref, bh_ref, o_ref, *, H):
 @functools.partial(jax.jit, static_argnames=("tile_b", "interpret"))
 def gru_cell_pallas(x, h, wi, wh, bi, bh, *, tile_b: int = 128,
                     interpret: bool = True):
-    """x: (B, Din), h: (B, H), wi: (Din, 3H), wh: (H, 3H), bi/bh: (3H,).
-    All dims must be pre-padded (ops.py): B % tile_b == 0, H % 128 == 0.
+    """x: (B, Din), h: (B, H), wi: (Din, 3H), wh: (H, 3H), bi/bh: (3H,)
+    or (1, 3H). All dims must be pre-padded (ops.py): B % tile_b == 0,
+    H % 128 == 0.
     """
     B, Din = x.shape
     H = h.shape[1]
@@ -55,4 +56,4 @@ def gru_cell_pallas(x, h, wi, wh, bi, bh, *, tile_b: int = 128,
         out_specs=pl.BlockSpec((tile_b, H), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H), h.dtype),
         interpret=interpret,
-    )(x, h, wi, wh, bi[None], bh[None])
+    )(x, h, wi, wh, bi.reshape(1, -1), bh.reshape(1, -1))

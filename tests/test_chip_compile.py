@@ -15,6 +15,8 @@ pytest-xdist every worker imports this file.
 """
 import dataclasses
 import os
+import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -116,23 +118,131 @@ def _static(one_chip, cfg, N, L, K, lead=()):
     }
 
 
+@pytest.fixture(scope="module")
+def scan_hlo(one_chip):
+    """The compiled text of the whole m4 event scan in pallas mode, single
+    or vmapped (`scan_hlo(batched)`), each compiled once for the module.
+    The dispatch asks `jax.default_backend()`, which sees the CPU here;
+    the fixture steers it to the described chip's platform."""
+    texts = {}
+
+    def compiled(batched):
+        if batched not in texts:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(dispatch, "_platform", lambda: "tpu")
+                cfg = dispatch.canonicalize_cfg(PAPER)
+                assert cfg.kernel_mode == "pallas"
+                size = BATCH if batched else SINGLE
+                lead = (size["B"],) if batched else ()
+                N, L = size["N"], size["L"]
+                fn = (sim._open_loop_scan_batched if batched
+                      else sim._open_loop_scan)
+                texts[batched] = _compile(
+                    fn, _params(one_chip, cfg), cfg, L,
+                    _static(one_chip, cfg, N, L, size["K"], lead),
+                    _sds(one_chip, lead + (N,), jnp.int32),
+                    _sds(one_chip, lead + (N,)))
+        return texts[batched]
+    return compiled
+
+
 @pytest.mark.parametrize("batched", [False, True])
-def test_open_loop_scan_compiles(one_chip, monkeypatch, batched):
-    """The whole m4 event scan in pallas mode, single and vmapped. The
-    dispatch asks `jax.default_backend()`, which sees the CPU here; the
-    test steers it to the described chip's platform."""
-    monkeypatch.setattr(dispatch, "_platform", lambda: "tpu")
-    cfg = dispatch.canonicalize_cfg(PAPER)
-    assert cfg.kernel_mode == "pallas"
-    size = BATCH if batched else SINGLE
-    lead = (size["B"],) if batched else ()
-    N, L = size["N"], size["L"]
-    fn = sim._open_loop_scan_batched if batched else sim._open_loop_scan
-    hlo = _compile(fn, _params(one_chip, cfg), cfg, L,
-                   _static(one_chip, cfg, N, L, size["K"], lead),
-                   _sds(one_chip, lead + (N,), jnp.int32),
-                   _sds(one_chip, lead + (N,)))
-    assert "tpu_custom_call" in hlo
+def test_open_loop_scan_compiles(scan_hlo, batched):
+    """The whole m4 event scan in pallas mode, single and vmapped."""
+    assert "tpu_custom_call" in scan_hlo(batched)
+
+
+def _close(text, i):
+    """Index of the parenthesis that closes the one at `text[i]`."""
+    depth = 0
+    for j in range(i, len(text)):
+        depth += {"(": 1, ")": -1}.get(text[j], 0)
+        if depth == 0:
+            return j
+    raise ValueError(text)
+
+
+def _hlo_computations(text):
+    """Compiled HLO text -> ({computation: {instruction: dict(shape, op,
+    operands, called)}}, entry name). Shapes lose their layouts."""
+    comps, cur, entry = {}, None, None
+    for line in text.splitlines():
+        if not line.startswith(" ") and line.endswith("{"):
+            head = line.split(" (")[0].split()
+            cur = comps.setdefault(head[-1].lstrip("%"), {})
+            if head[0] == "ENTRY":
+                entry = head[-1].lstrip("%")
+        elif cur is not None and line.lstrip().startswith(("%", "ROOT %")):
+            lhs, rhs = line.strip().removeprefix("ROOT ").split(" = ", 1)
+            end = _close(rhs, 0) + 1 if rhs.startswith("(") else rhs.index(" ")
+            rest = rhs[end:].lstrip()
+            op = rest[:rest.index("(")]
+            close = _close(rest, len(op))
+            cur[lhs.lstrip("%")] = dict(
+                shape=re.sub(r"\{[^}]*\}", "", rhs[:end]), op=op,
+                operands=re.findall(r"%([\w.\-]+)", rest[len(op):close]),
+                called=re.findall(r"%([\w.\-]+)", rest[close:]))
+    return comps, entry
+
+
+def _loop_computations(comps, entry):
+    """Every computation that the entry's while loops run, nested ones
+    (fusions, inner loops) included."""
+    todo = [c for ins in comps[entry].values() if ins["op"] == "while"
+            for c in ins["called"] if c in comps]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [d for ins in comps[c].values() for d in ins["called"]
+                     if d in comps]
+    return seen
+
+
+# the staged kernel layouts at paper widths (H 400 -> 512, G 300 -> 384,
+# Din 13 and 309 -> 128 and 384) and the per-gate slices of unstaged GRU
+# weights
+STAGED_SHAPES = {"f32[512,1536]", "f32[128,1536]", "f32[384,1536]",
+                 "f32[768,384]", "f32[1,1536]", "f32[400,400]"}
+# ops that hand a value on without computing it: the loop's own carry,
+# and the async copies that move it between memory spaces
+PASS_ON = {"parameter", "get-tuple-element", "tuple", "copy-start",
+           "copy-done"}
+# kernel -> positions of its weight and bias operands
+WEIGHT_OPERANDS = {"gru_cell_pallas": range(2, 6),
+                   "bipartite_round_pallas": range(3, 7)}
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_open_loop_scan_stages_weights_once(scan_hlo, batched):
+    """The kernels' weight layout is built before the event loop, not in
+    it: no op in the loop computes a staged weight or bias shape, and
+    each weight operand of the seven kernel calls per event is a value
+    the loop carries unchanged."""
+    comps, entry = _hlo_computations(scan_hlo(batched))
+    loop = _loop_computations(comps, entry)
+    built = sorted(f"{name} = {ins['shape']} {ins['op']}"
+                   for c in loop for name, ins in comps[c].items()
+                   if ins["shape"] in STAGED_SHAPES
+                   and ins["op"] not in PASS_ON)
+    assert not built, built[:8]
+    calls = Counter()
+    for c in loop:
+        body = comps[c]
+        for name, ins in body.items():
+            kernel = name.rsplit(".", 1)[0]
+            if kernel not in WEIGHT_OPERANDS:
+                continue
+            calls[kernel] += 1
+            for i in WEIGHT_OPERANDS[kernel]:
+                src = ins["operands"][i]
+                while body[src]["op"] in ("copy-start", "copy-done"):
+                    src = body[src]["operands"][0]
+                assert body[src]["op"] == "get-tuple-element", (name, i, src)
+                carry = body[body[src]["operands"][0]]
+                assert carry["op"] == "parameter", (name, i, src)
+    assert calls == {"gru_cell_pallas": 4, "bipartite_round_pallas": 3}
 
 
 @pytest.mark.parametrize("batched", [False, True])

@@ -1,5 +1,9 @@
 """Per-kernel allclose sweeps (Pallas interpret=True vs pure-jnp oracle)
 plus hypothesis property tests on the water-filling invariants."""
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -176,3 +180,196 @@ def test_waterfill_single_link_fair_share(n, seed):
     paths = [np.array([0])] * n
     rates = waterfill_np(cap, paths)
     np.testing.assert_allclose(rates, 7e9 / n, rtol=1e-9)
+
+
+# ------------------------------------------------------------- staging
+@pytest.mark.parametrize("B,Din,H", [
+    (5, 7, 20), (64, 13, 400), (64, 309, 400),
+])
+def test_staged_gru_matches_gru_cell(B, Din, H):
+    """Weights staged once, in a program of their own, then handed to the
+    per-call wrapper: the cell is bit-equal to `ops.gru_cell`, and the
+    layout keeps each gate third at a multiple of the padded width."""
+    from repro.kernels.fused_gru.ops import gru_cell_staged, stage_gru
+    rng = np.random.default_rng(B + Din + H)
+    x = jnp.asarray(rng.normal(size=(B, Din)), jnp.float32)
+    h = jnp.asarray(rng.normal(size=(B, H)), jnp.float32)
+    wi = jnp.asarray(rng.normal(size=(Din, 3 * H)) * 0.1, jnp.float32)
+    wh = jnp.asarray(rng.normal(size=(H, 3 * H)) * 0.1, jnp.float32)
+    bi = jnp.asarray(rng.normal(size=(3 * H,)) * 0.1, jnp.float32)
+    bh = jnp.asarray(rng.normal(size=(3 * H,)) * 0.1, jnp.float32)
+    w = jax.jit(stage_gru)(wi, wh, bi, bh)
+    Dp, Hp = Din + (-Din) % 128, H + (-H) % 128
+    assert {k: v.shape for k, v in w.items()} == {
+        "wi": (Dp, 3 * Hp), "wh": (Hp, 3 * Hp),
+        "bi": (1, 3 * Hp), "bh": (1, 3 * Hp)}
+    for staged, orig in ((w["wi"], wi), (w["wh"], wh), (w["bi"], bi[None]),
+                         (w["bh"], bh[None])):
+        want = np.zeros(staged.shape, np.float32)
+        for g in range(3):
+            want[:orig.shape[0], g * Hp:g * Hp + H] = orig[:, g * H:(g + 1) * H]
+        np.testing.assert_array_equal(np.asarray(staged), want)
+    staged_out = jax.jit(gru_cell_staged, static_argnames="tile_b")(
+        x, h, w, tile_b=8)
+    np.testing.assert_array_equal(
+        np.asarray(staged_out),
+        np.asarray(gru_pallas(x, h, wi, wh, bi, bh, tile_b=8)))
+
+
+@pytest.mark.parametrize("SF,SL,G,P", [(8, 16, 20, 4), (64, 128, 300, 8)])
+def test_staged_gnn_round_matches_bipartite_round(SF, SL, G, P):
+    """As above for one GNN round: bit-equal to `ops.bipartite_round`, with
+    the [self; agg] halves of each weight at rows 0 and Gp."""
+    from repro.kernels.bipartite.ops import bipartite_round_staged, stage_round
+    rng = np.random.default_rng(SF + G)
+    E = SF * P
+    f = jnp.asarray(rng.normal(size=(SF, G)), jnp.float32)
+    l = jnp.asarray(rng.normal(size=(SL, G)), jnp.float32)
+    edge_f = jnp.repeat(jnp.arange(SF), P)
+    edge_l = jnp.asarray(rng.integers(0, SL, E), jnp.int32)
+    edge_mask = jnp.asarray(rng.random(E) < 0.7, jnp.float32)
+    wf = jnp.asarray(rng.normal(size=(2 * G, G)) * 0.1, jnp.float32)
+    wl = jnp.asarray(rng.normal(size=(2 * G, G)) * 0.1, jnp.float32)
+    bf = jnp.asarray(rng.normal(size=(G,)) * 0.1, jnp.float32)
+    bl = jnp.asarray(rng.normal(size=(G,)) * 0.1, jnp.float32)
+    w = jax.jit(stage_round)(wf, wl, bf, bl)
+    Gp = G + (-G) % 128
+    for staged, orig in ((w["wf"], wf), (w["wl"], wl)):
+        want = np.zeros((2 * Gp, Gp), np.float32)
+        want[:G, :G], want[Gp:Gp + G, :G] = orig[:G], orig[G:]
+        np.testing.assert_array_equal(np.asarray(staged), want)
+    for staged, orig in ((w["bf"], bf), (w["bl"], bl)):
+        want = np.zeros((1, Gp), np.float32)
+        want[0, :G] = orig
+        np.testing.assert_array_equal(np.asarray(staged), want)
+    got = jax.jit(bipartite_round_staged)(f, l, edge_f, edge_l, edge_mask, w)
+    ref = bipartite_round(f, l, edge_f, edge_l, edge_mask, wf, wl, bf, bl)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+SMALL = dict(hidden=16, gnn_dim=12, mlp_hidden=8, gnn_layers=2,
+             snap_flows=8, snap_links=24)
+
+
+def _staged_count(mode):
+    from repro.obs.registry import get_registry, labeled
+    return get_registry().snapshot()["counters"].get(
+        labeled("kernels.staged", mode=mode), 0)
+
+
+def test_stage_params_adds_kernel_layout():
+    """The kernel modes add the staged tree beside the original entries;
+    "xla" mode gets `params` back untouched and stages nothing."""
+    from repro.core.model import M4Config, init_m4
+    from repro.kernels import dispatch
+    params = init_m4(jax.random.PRNGKey(0), M4Config(**SMALL))
+    before = _staged_count("xla")
+    assert dispatch.stage_params(params, "xla") is params
+    assert dispatch.kernel_params(params, "xla") is params
+    assert _staged_count("xla") == before
+    before = _staged_count("interpret")
+    staged = dispatch.stage_params(params, "interpret")
+    assert _staged_count("interpret") == before + 1
+    assert all(staged[k] is params[k] for k in params)
+    w = dispatch.kernel_params(staged, "interpret")
+    assert set(w) == set(dispatch.GRUS) | {"gnn"}
+    assert w["gru1"]["wh"].shape == (128, 3 * 128)
+    assert len(w["gnn"]) == SMALL["gnn_layers"]
+    assert w["gnn"][0]["wf"].shape == (256, 128)
+    with pytest.raises(ValueError, match="stage_params"):
+        dispatch.kernel_params(params, "interpret")
+
+
+def _scenario(seed=3):
+    from repro.scenarios.spec import ScenarioSpec
+    sc = ScenarioSpec(name=f"staging-{seed}", topo="ft-4x2x2",
+                      workload="table2", size_dist="WebServer",
+                      max_load=0.4, num_flows=24, seed=seed).to_scenario()
+    return sc.topo, sc.config, sc.generate()
+
+
+def test_open_loop_scan_stages_once_per_trace():
+    """Tracing one event scan stages the weights exactly once, before the
+    loop: the step inside reads the staged tree and cannot stage."""
+    from repro.core import simulate as sim
+    from repro.core.model import M4Config, init_m4
+    cfg = M4Config(**SMALL, kernel_mode="interpret")
+    params = init_m4(jax.random.PRNGKey(1), cfg)
+    topo, net, flows = _scenario()
+    static, L, _ = sim.make_static(topo, flows, net, cfg)
+    order, times = sim._arrival_order(static)
+    traces = sim.TRACE_COUNTS["open_loop"]
+    before = _staged_count("interpret")
+    # a length no other test compiles, so this call traces
+    sim._open_loop_scan.lower(params, cfg, L, static, jnp.asarray(order),
+                              jnp.asarray(times), num_events=7)
+    assert sim.TRACE_COUNTS["open_loop"] == traces + 1
+    assert _staged_count("interpret") == before + 1
+
+
+def test_staged_paths_match_xla():
+    """The open loop (`simulate_open_loop`) and the closed loop
+    (`M4Simulator`, staged once per session) in "interpret" mode give the
+    FCTs of the "xla" path."""
+    import dataclasses
+
+    from repro.core import simulate as sim
+    from repro.core.model import M4Config, init_m4
+    cfg = M4Config(**SMALL)
+    params = init_m4(jax.random.PRNGKey(2), cfg)
+    topo, net, flows = _scenario(5)
+    fcts, closed = {}, {}
+    for mode in ("xla", "interpret"):
+        c = dataclasses.replace(cfg, kernel_mode=mode)
+        fcts[mode] = sim.simulate_open_loop(params, c, topo, net, flows).fcts
+        s = sim.M4Simulator(params, c, topo, net, flows[:4])
+        for fid in range(4):
+            s.inject_arrival(fid, float(flows[fid].t_arrival))
+        t, fid = s.next_departure()
+        s.commit_departure(fid, t)
+        closed[mode] = (fid, t)
+    np.testing.assert_allclose(fcts["interpret"], fcts["xla"], rtol=1e-4)
+    assert closed["interpret"][0] == closed["xla"][0]
+    np.testing.assert_allclose(closed["interpret"][1], closed["xla"][1],
+                               rtol=1e-4)
+
+
+def test_sharded_scan_stages_once_subprocess():
+    """With two (forced host) devices the batch takes the pmap scan: in
+    "interpret" mode it stages once for its one trace, and its FCTs are
+    the "xla" path's."""
+    code = """
+import dataclasses, jax, numpy as np
+assert jax.local_device_count() == 2, jax.devices()
+from repro.core import simulate as sim
+from repro.core.model import M4Config, init_m4
+from repro.obs.registry import get_registry, labeled
+from repro.scenarios import get_suite
+cfg = M4Config(hidden=16, gnn_dim=12, mlp_hidden=8, gnn_layers=2,
+               snap_flows=8, snap_links=24)
+params = init_m4(jax.random.PRNGKey(0), cfg)
+scens = []
+for spec in get_suite("smoke16", num_flows=8).limit(4):
+    sc = spec.to_scenario()
+    scens.append((sc.topo, sc.config, sc.generate()))
+fcts = {m: sim.simulate_open_loop_batch(
+            params, dataclasses.replace(cfg, kernel_mode=m), scens)
+        for m in ("xla", "interpret")}
+assert sim.TRACE_COUNTS["open_loop_sharded"] == 2, dict(sim.TRACE_COUNTS)
+counters = get_registry().snapshot()["counters"]
+assert counters.get(labeled("kernels.staged", mode="interpret")) == 1
+assert labeled("kernels.staged", mode="xla") not in counters
+for a, b in zip(fcts["xla"], fcts["interpret"]):
+    np.testing.assert_allclose(b.fcts, a.fcts, rtol=1e-4)
+print("sharded-staged-ok")
+"""
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=2",
+               JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "sharded-staged-ok" in out.stdout
