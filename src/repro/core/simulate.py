@@ -18,7 +18,9 @@ equivalence oracle for tests (`_build_snapshot_dense`).
 `jax.vmap`s the scan across them — one compiled call instead of B retraces
 (this is what `repro.sim.get_backend("m4").run_many` dispatches to) —
 and `jax.pmap`-shards the vmapped batch across local devices when more
-than one exists (params broadcast, arenas split devices x B/devices).
+than one exists (params broadcast, arenas split devices x B/devices), or
+across the devices a caller gives; given one, it runs the vmapped scan
+there. Each batch sets `m4.batch.*` padding gauges (`repro.obs`).
 `M4Simulator` exposes a single-event step for closed-loop applications that
 inject flows dynamically (§5.4); its jitted step donates the state arenas
 so the carry is updated in place instead of copied every event.
@@ -45,7 +47,7 @@ from __future__ import annotations
 import time
 from collections import Counter
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -53,6 +55,7 @@ import numpy as np
 
 from ..kernels.dispatch import canonicalize_cfg, resolve_mode, stage_params
 from ..nn import mlp
+from ..obs.registry import get_registry
 from ..obs.trace import get_tracer
 from .model import (MATMUL_PRECISION, M4Config, predict_queue,
                     predict_size, predict_sldn, spatial_update,
@@ -446,12 +449,10 @@ def _open_loop_scan_batched(params, cfg: M4Config, num_links: int, static,
     return jax.vmap(one)(static, arr_order, arr_times)
 
 
-@partial(jax.pmap, static_broadcasted_argnums=(1, 2),
-         in_axes=(None, None, None, 0, 0, 0))
-def _open_loop_scan_sharded(params, cfg: M4Config, num_links: int, static,
-                            arr_order, arr_times):
-    """pmap(vmap(scan)): params broadcast to every local device, scenario
-    arenas sharded (D, B/D, ...) across them — one compile per sweep chunk,
+def _sharded_body(params, cfg: M4Config, num_links: int, static, arr_order,
+                  arr_times):
+    """pmap(vmap(scan)): params broadcast to every device, scenario arenas
+    sharded (D, B/D, ...) across them — one compile per sweep chunk,
     N/devices scenarios of work per device."""
     TRACE_COUNTS["open_loop_sharded"] += 1
 
@@ -459,6 +460,15 @@ def _open_loop_scan_sharded(params, cfg: M4Config, num_links: int, static,
         return _open_loop_core(params, cfg, num_links, s, o, t)
 
     return jax.vmap(one)(static, arr_order, arr_times)
+
+
+@lru_cache(maxsize=None)
+def _sharded_scan(devices=None):
+    """The sharded scan over `devices` (a tuple), or over every local
+    device (None)."""
+    return jax.pmap(_sharded_body, static_broadcasted_argnums=(1, 2),
+                    in_axes=(None, None, None, 0, 0, 0), devices=devices)
+
 
 
 @dataclass
@@ -625,15 +635,33 @@ def simulate_open_loop(params, cfg: M4Config, topo, net_config, flows, *,
                             probes=series)
 
 
-def stack_scenarios(cfg: M4Config, scenarios):
+def batch_shape(cfg: M4Config, scenarios) -> list:
+    """(flows, links, link degree K) of each (topo, net_config, flows)
+    scenario: what `stack_scenarios` pads to the batch's largest."""
+    return [(len(flows), topo.num_links, max_link_degree(flows, cfg.max_path))
+            for topo, _, flows in scenarios]
+
+
+def padding_shares(shape) -> dict:
+    """The batch gauges of a `batch_shape`: its size, and the share of the
+    padded membership slots (K), events (2·N) and links that is padding,
+    1 − Σxᵢ / (B·max x)."""
+    B = len(shape)
+    n, l, k = (np.asarray(c, np.float64) for c in zip(*shape))
+    return {"m4.batch.size": B,
+            "m4.batch.k_pad_share": float(1 - k.sum() / (B * k.max())),
+            "m4.batch.event_pad_share": float(1 - n.sum() / (B * n.max())),
+            "m4.batch.link_pad_share": float(1 - l.sum() / (B * l.max()))}
+
+
+def stack_scenarios(cfg: M4Config, scenarios, shape=None):
     """Pad (topo, net_config, flows) scenarios to one arena shape and
     stack them: returns (static, arrival order, arrival times, padded
     link count, per-scenario ideal FCTs) — the inputs of the batched
-    scans, whose leading axis is the scenario."""
-    n_max = max(len(flows) for _, _, flows in scenarios)
-    l_max = max(topo.num_links for topo, _, _ in scenarios)
-    k_max = max(max_link_degree(flows, cfg.max_path)
-                for _, _, flows in scenarios)
+    scans, whose leading axis is the scenario. `shape` is the scenarios'
+    `batch_shape`, when the caller has it."""
+    shape = batch_shape(cfg, scenarios) if shape is None else shape
+    n_max, l_max, k_max = (max(c) for c in zip(*shape))
     statics, orders, times, ideals = [], [], [], []
     for topo, net_config, flows in scenarios:
         static, _, ideal = make_static(topo, flows, net_config, cfg,
@@ -651,7 +679,8 @@ def stack_scenarios(cfg: M4Config, scenarios):
 
 def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
                              snapshot_impl="incremental",
-                             probes: ProbeConfig = None) -> list:
+                             probes: ProbeConfig = None,
+                             devices=None) -> list:
     """Run many scenarios in ONE compiled vmapped scan.
 
     scenarios: sequence of (topo, net_config, flows). Arenas are padded to
@@ -661,20 +690,35 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
     per-scenario intermediate-state series (vmapped ring buffers, sliced
     and trimmed per scenario on the host); the multi-device sharded path
     is probe-free, so probed batches stay on the vmapped path.
+
+    `devices` holds the batch to those devices: one runs the vmapped scan
+    there, several shard the batch across them. None (the default) shards
+    across every local device when there is more than one. A caller that
+    owns one chip of a host passes that chip, so that what it runs, and
+    what it measures, does not depend on how many chips the host exposes.
+
+    The call is the `m4.run_many` span (children `m4.build`, `m4.scan`,
+    `m4.result`); the batch's `padding_shares` are set as registry gauges
+    and as attributes of that span.
     """
     scenarios = list(scenarios)
     if not scenarios:
         return []
     tracer = get_tracer()
-    with tracer.span("m4.run_many"):
+    with tracer.span("m4.run_many") as span:
         cfg = canonicalize_cfg(cfg)
         probes = normalize_probes(probes, M4_CHANNELS)
-        n_max = max(len(flows) for _, _, flows in scenarios)
         with tracer.span("m4.build"):
+            shape = batch_shape(cfg, scenarios)
             batched, order_b, times_b, l_max, ideals = stack_scenarios(
-                cfg, scenarios)
-        counts = [len(flows) for _, _, flows in scenarios]
-        D = jax.local_device_count()
+                cfg, scenarios, shape)
+        registry = get_registry()
+        for name, value in padding_shares(shape).items():
+            registry.set_gauge(name, value)
+            span.attr(name, value)
+        counts = [n for n, _, _ in shape]
+        n_max = max(counts)
+        D = jax.local_device_count() if devices is None else len(devices)
         sharded = (D > 1 and len(scenarios) >= D
                    and snapshot_impl == "incremental" and probes is None)
         bufs = None
@@ -682,12 +726,16 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
             t0 = time.perf_counter()
             if sharded:
                 from .sharding import shard_leaves
-                res = _open_loop_scan_sharded(
-                    params, cfg, l_max, shard_leaves(batched, D),
-                    shard_leaves(order_b, D), shard_leaves(times_b, D))
+                scan = _sharded_scan(None if devices is None
+                                     else tuple(devices))
+                res = scan(params, cfg, l_max, shard_leaves(batched, D),
+                           shard_leaves(order_b, D), shard_leaves(times_b, D))
             else:
+                args = (params, batched, order_b, times_b)
+                if devices is not None:
+                    args = jax.device_put(args, devices[0])
                 res = _open_loop_scan_batched(
-                    params, cfg, l_max, batched, order_b, times_b,
+                    args[0], cfg, l_max, *args[1:],
                     snapshot_impl=snapshot_impl, probes=probes)
             res = jax.block_until_ready(res)
             wall = time.perf_counter() - t0

@@ -274,7 +274,12 @@ class M4Backend(Backend):
                          wall_time=r.wallclock, backend=self.name,
                          probes=r.probes, raw=r)
 
-    def run_many(self, requests: Sequence[SimRequest]) -> List[SimResult]:
+    def run_many(self, requests: Sequence[SimRequest],
+                 devices=None) -> List[SimResult]:
+        """One vmapped scan over the batch. `devices` holds it to those
+        devices (one chip of a host: the vmapped scan there, whatever
+        the host's chip count); None shards across every local device
+        when there is more than one (`simulate_open_loop_batch`)."""
         from ..core.simulate import simulate_open_loop_batch
         for r in requests:
             self._check(r)
@@ -282,7 +287,7 @@ class M4Backend(Backend):
         results = simulate_open_loop_batch(
             self.params, self.cfg,
             [(r.topo, r.config, list(r.flows)) for r in requests],
-            probes=probes)
+            probes=probes, devices=devices)
         return [SimResult(fcts=r.fcts, slowdowns=r.slowdowns,
                           wall_time=r.wallclock, backend=self.name,
                           probes=r.probes, raw=r)
