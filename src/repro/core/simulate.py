@@ -152,7 +152,11 @@ def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links,
                  legacy=False):
     """Snapshot link set (deduped, padded) + edge list — all snapshot-sized
     (SF·P), no full-arena pass. `legacy=True` reproduces the seed program's
-    jnp.unique dedupe (same output, slower lowering on CPU)."""
+    jnp.unique dedupe (same output, slower lowering on CPU).
+
+    An edge's slot is its link's rank in the sorted set, counted by one
+    (SF·P, SL) comparison: a binary search lowers to a `while` loop of
+    gathers, nested in the event scan on the chip."""
     SF, P, SL = cfg.snap_flows, cfg.max_path, cfg.snap_links
     gl = flow_links[snap_f]                                  # (SF, P)
     gl = jnp.where((gl >= 0) & (snap_f_mask[:, None] > 0), gl, num_links)
@@ -162,7 +166,7 @@ def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links,
         uniq = _dedupe_ascending(gl.reshape(-1), SL, num_links)
     snap_l = uniq
     snap_l_mask = (uniq < num_links).astype(jnp.float32)
-    el = jnp.searchsorted(uniq, gl.reshape(-1))
+    el = (uniq[None, :] < gl.reshape(-1, 1)).sum(-1, dtype=jnp.int32)
     edge_mask = (gl.reshape(-1) < num_links).astype(jnp.float32)
     el = jnp.where(edge_mask > 0, jnp.minimum(el, SL - 1), 0)
     return snap_l, snap_l_mask, el, edge_mask

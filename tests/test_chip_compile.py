@@ -246,6 +246,22 @@ def test_open_loop_scan_stages_weights_once(scan_hlo, batched):
 
 
 @pytest.mark.parametrize("batched", [False, True])
+def test_open_loop_scan_has_no_nested_loop(scan_hlo, batched):
+    """The event loop's body runs no loop of its own: the snapshot's edge
+    slots are a comparison rank, not a binary search (a `while` of
+    gathers per event)."""
+    text = scan_hlo(batched)
+    comps, entry = _hlo_computations(text)
+    loop = _loop_computations(comps, entry)
+    nested = sorted(f"{c}: {name}" for c in loop
+                    for name, ins in comps[c].items() if ins["op"] == "while")
+    assert not nested, nested[:8]
+    named = [n for n in re.findall(r'op_name="([^"]*)"', text)
+             if "searchsorted" in n]
+    assert not named, named[:4]
+
+
+@pytest.mark.parametrize("batched", [False, True])
 def test_flowsim_fast_scan_compiles(one_chip, batched):
     B, N, L = BATCH["B"], BATCH["N"], BATCH["L"]
     lead = (B,) if batched else ()

@@ -89,6 +89,80 @@ def test_incremental_builder_matches_dense(seed):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+PAPER_SNAP = M4Config()      # SF 64, P 8, SL 128
+SLOT_CASES = ("random", "empty", "full", "dup", "masked", "overflow")
+
+
+def _snapshot_links(cfg, case, num_links, rng):
+    """(flow_links, snap_f, snap_f_mask) of one snapshot whose edges hit
+    the link set as `case` says."""
+    SF, P, SL = cfg.snap_flows, cfg.max_path, cfg.snap_links
+    N = 2 * SF
+    pool = {"dup": rng.choice(num_links, size=3, replace=False),
+            "full": rng.choice(num_links, size=SL, replace=False)}.get(
+                case, np.arange(num_links))
+    links = rng.choice(pool, size=(N, P))
+    links[rng.random((N, P)) < 0.2] = -1          # short paths
+    snap_f = rng.permutation(N)[:SF]
+    if case in ("full", "overflow"):              # every hop a link
+        links[snap_f] = rng.choice(pool, size=(SF, P))
+    if case == "full":                            # each of the SL once
+        flat = links[snap_f].reshape(-1)
+        flat[:SL] = pool
+        links[snap_f] = flat.reshape(SF, P)
+    mask = np.ones(SF, np.float32)
+    if case == "empty":
+        mask[:] = 0
+    elif case == "masked":
+        mask[rng.random(SF) < 0.5] = 0
+    return (jnp.asarray(links, jnp.int32), jnp.asarray(snap_f, jnp.int32),
+            jnp.asarray(mask))
+
+
+def _slots_by_binary_search(cfg, flow_links, snap_f, snap_f_mask, uniq,
+                            num_links):
+    """The edge slots as the seed program found them: `searchsorted`'s
+    binary search, then the same masking and clamping."""
+    gl = flow_links[snap_f]
+    gl = jnp.where((gl >= 0) & (snap_f_mask[:, None] > 0), gl, num_links)
+    el = jnp.searchsorted(uniq, gl.reshape(-1), method="scan")
+    return jnp.where(gl.reshape(-1) < num_links,
+                     jnp.minimum(el, cfg.snap_links - 1), 0)
+
+
+@pytest.mark.parametrize("case", SLOT_CASES)
+@pytest.mark.parametrize("cfg, num_links", [(TINY, 60), (PAPER_SNAP, 1536)],
+                         ids=["tiny", "paper"])
+def test_build_links_slots_match_binary_search(cfg, num_links, case):
+    """The comparison-rank edge slots equal a binary search's, one snapshot
+    at a time and vmapped over 8 lanes."""
+    rng = np.random.default_rng([num_links, SLOT_CASES.index(case)])
+    snaps = [_snapshot_links(cfg, case, num_links, rng) for _ in range(8)]
+
+    def both(flow_links, snap_f, snap_f_mask):
+        snap_l, slm, el, _ = sim._build_links(cfg, flow_links, snap_f,
+                                              snap_f_mask, num_links)
+        want = _slots_by_binary_search(cfg, flow_links, snap_f, snap_f_mask,
+                                       snap_l, num_links)
+        return el, want, slm.sum()
+
+    for snap in snaps[:2]:
+        got, want, _ = both(*snap)
+        assert got.dtype == jnp.int32
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got, want, n_links = jax.vmap(both)(*(jnp.stack(a) for a in zip(*snaps)))
+    assert got.shape == (8, cfg.snap_flows * cfg.max_path)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # each case reaches the link set it names
+    SL = cfg.snap_links
+    n_links = np.asarray(n_links).tolist()
+    want_links = {"empty": 0, "full": SL, "overflow": SL, "dup": 3}
+    if case in want_links:
+        assert n_links == [want_links[case]] * 8
+    else:
+        assert all(0 < n <= SL for n in n_links)
+
+
 def test_dedupe_ascending_matches_unique():
     rng = np.random.default_rng(0)
     for k in (8, 15, 32, 48):           # both regimes of the dedupe
