@@ -18,7 +18,6 @@ In the kernel modes they read the weights from the layout that
 from __future__ import annotations
 
 from dataclasses import dataclass
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -110,37 +109,15 @@ def link_static_feat(capacity):
 
 
 # ---------------------------------------------------------------- GNN
-def _bipartite_round(layer, f_emb, l_emb, edge_f, edge_l, edge_mask, n_links):
-    """One GraphSAGE round with sum aggregation.
-
-    f_emb: (F, G), l_emb: (L, G); edges (E,) flow-slot / link-slot / mask.
-    """
-    ef = f_emb[edge_f] * edge_mask[:, None]
-    agg_l = jax.ops.segment_sum(ef, edge_l, num_segments=n_links)
-    el = l_emb[edge_l] * edge_mask[:, None]
-    agg_f = jax.ops.segment_sum(el, edge_f, num_segments=f_emb.shape[0])
-    f_new = jax.nn.relu(linear(layer["wf"], jnp.concatenate([f_emb, agg_f], -1)))
-    l_new = jax.nn.relu(linear(layer["wl"], jnp.concatenate([l_emb, agg_l], -1)))
-    return f_new, l_new
-
-
-def gnn_forward(params, cfg: M4Config, f_h, l_h, edge_f, edge_l, edge_mask,
-                ref_impl=False):
+def gnn_forward(params, cfg: M4Config, f_h, l_h, edge_f, edge_l, edge_mask):
     """f_h: (SNAP_F, H), l_h: (SNAP_L, H) -> GNN embeddings (·, G).
 
-    `ref_impl=True` forces the original segment-sum formulation (the seed
-    program) regardless of kernel mode — kept as the oracle behind the
-    legacy dense event step and the kernel parity tests; the production
-    path goes through `repro.kernels.dispatch` (incidence matmuls on XLA,
-    the fused Pallas kernel on TPU — same math, different execution)."""
+    The rounds go through `repro.kernels.dispatch`: incidence matmuls on
+    XLA, the fused Pallas kernel on TPU — same math, different execution.
+    `kernels/bipartite/ref.py` holds the segment-sum form as the oracle."""
     from ..kernels import dispatch
     f = jax.nn.relu(linear(params["proj_f"], f_h))
     l = jax.nn.relu(linear(params["proj_l"], l_h))
-    if ref_impl:
-        for layer in params["gnn"]:
-            f, l = _bipartite_round(layer, f, l, edge_f, edge_l, edge_mask,
-                                    cfg.snap_links)
-        return f, l
     mode = dispatch.resolve_mode(cfg.kernel_mode)
     return dispatch.gnn_rounds(dispatch.kernel_params(params, mode)["gnn"],
                                f, l, edge_f, edge_l, edge_mask,
@@ -169,40 +146,29 @@ def predict_queue(params, link_h):
 
 # ---------------------------------------------------------------- one event
 def temporal_update(params, cfg: M4Config, f_h, l_h, dt_f, dt_l,
-                    f_feat, l_feat, cfg_vec, ref_impl=False):
-    """GRU-1 / GRU-A temporal advance of snapshot states (`ref_impl=True`
-    runs the seed program: two independent reference cells)."""
+                    f_feat, l_feat, cfg_vec):
+    """GRU-1 / GRU-A temporal advance of snapshot states."""
     from ..kernels import dispatch
-    mode = "xla" if ref_impl else dispatch.resolve_mode(cfg.kernel_mode)
+    mode = dispatch.resolve_mode(cfg.kernel_mode)
     Bf, Bl = f_h.shape[0], l_h.shape[0]
     cf = jnp.broadcast_to(cfg_vec, (Bf, cfg_vec.shape[-1]))
     cl = jnp.broadcast_to(cfg_vec, (Bl, cfg_vec.shape[-1]))
     xin_f = jnp.concatenate([time_feat(dt_f)[:, None], f_feat, cf], -1)
     xin_l = jnp.concatenate([time_feat(dt_l)[:, None], l_feat, cl], -1)
-    if ref_impl:
-        from ..nn.layers import gru_cell as gru_ref
-        return (gru_ref(params["gru1"], xin_f, f_h),
-                gru_ref(params["gruA"], xin_l, l_h))
     w = dispatch.kernel_params(params, mode)
     return dispatch.gru_cell_pair(w["gru1"], w["gruA"],
                                   xin_f, f_h, xin_l, l_h, mode=mode)
 
 
 def spatial_update(params, cfg: M4Config, f_h, l_h, edge_f, edge_l, edge_mask,
-                   cfg_vec, ref_impl=False):
-    """GNN + GRU-2/GRU-B state refresh (`ref_impl` as in `gnn_forward`)."""
+                   cfg_vec):
+    """GNN + GRU-2/GRU-B state refresh."""
     from ..kernels import dispatch
-    mode = "xla" if ref_impl else dispatch.resolve_mode(cfg.kernel_mode)
-    gf, gl = gnn_forward(params, cfg, f_h, l_h, edge_f, edge_l, edge_mask,
-                         ref_impl=ref_impl)
+    mode = dispatch.resolve_mode(cfg.kernel_mode)
+    gf, gl = gnn_forward(params, cfg, f_h, l_h, edge_f, edge_l, edge_mask)
     Bf, Bl = f_h.shape[0], l_h.shape[0]
     cf = jnp.broadcast_to(cfg_vec, (Bf, cfg_vec.shape[-1]))
     cl = jnp.broadcast_to(cfg_vec, (Bl, cfg_vec.shape[-1]))
-    if ref_impl:   # seed program: two independent reference cells
-        from ..nn.layers import gru_cell as gru_ref
-        f_new = gru_ref(params["gru2"], jnp.concatenate([gf, cf], -1), f_h)
-        l_new = gru_ref(params["gruB"], jnp.concatenate([gl, cl], -1), l_h)
-        return f_new, l_new
     w = dispatch.kernel_params(params, mode)
     return dispatch.gru_cell_pair(w["gru2"], w["gruB"],
                                   jnp.concatenate([gf, cf], -1), f_h,

@@ -10,8 +10,7 @@ Per-event cost is O(path x link-degree), not O(N) (DESIGN.md §3):
 `make_static` precomputes a link->flow membership table and the scan
 carries a per-link active-flow occupancy bitmap, so the snapshot builder
 gathers candidates from the event flow's <= P links instead of comparing
-against all N flows. The O(N²·P²) dense builder survives only as the
-equivalence oracle for tests (`_build_snapshot_dense`).
+against all N flows.
 
 `simulate_open_loop` runs the whole trace as one `lax.scan` (2N events).
 `simulate_open_loop_batch` pads B scenarios to a shared arena shape and
@@ -72,42 +71,12 @@ BIG = 1e30
 TRACE_COUNTS = Counter()
 
 
-def _build_snapshot_dense(cfg: M4Config, flow_links, fid, active_mask):
-    """Reference oracle: affected flows = active flows sharing >= 1 link
-    with the event flow, found by a dense (N, P, P) comparison + top-k over
-    the whole arena. NOT the production path — `_build_snapshot` computes
-    the same set from the occupancy arenas in O(P·K); tests assert the two
-    emit identical snapshots."""
-    SF = cfg.snap_flows
-    ev_links = flow_links[fid]                               # (P,)
-    share = (flow_links[:, :, None] == ev_links[None, None, :]) \
-        & (flow_links[:, :, None] >= 0)
-    shares = share.any((1, 2))                               # (N,)
-    score = jnp.where(shares & active_mask, 1.0, 0.0).at[fid].set(-1.0)
-    # stable top-(SF-1) by score (ties -> lower index)
-    N = flow_links.shape[0]
-    key = score * N - jnp.arange(N, dtype=jnp.int32)
-    k = min(SF - 1, N)
-    _, idx = jax.lax.top_k(key, k)
-    others_valid = score[idx] > 0
-    pad = SF - 1 - k
-    if pad:
-        idx = jnp.concatenate([idx, jnp.zeros((pad,), idx.dtype)])
-        others_valid = jnp.concatenate([others_valid, jnp.zeros((pad,), bool)])
-    # masked slots scatter to the dump row N, never aliasing a live row
-    idx = jnp.where(others_valid, idx, N)
-    snap_f = jnp.concatenate([fid[None], idx])
-    snap_mask = jnp.concatenate([jnp.ones((1,), jnp.float32),
-                                 others_valid.astype(jnp.float32)])
-    return snap_f, snap_mask
-
-
 def _build_snapshot(cfg: M4Config, static, link_occ, fid):
     """Incremental snapshot builder: candidates come from the membership
     lists of the event flow's <= P links (O(P·K_max) work, independent of
-    arena size N), filtered by the carried occupancy bitmap. Emits exactly
-    what `_build_snapshot_dense` emits: slot 0 = event flow, then the
-    lowest-index active sharing flows ascending, dump index N beyond."""
+    arena size N), filtered by the carried occupancy bitmap. Emits slot
+    0 = event flow, then the lowest-index active flows that share a link
+    with it, ascending, and dump index N beyond."""
     SF = cfg.snap_flows
     N = static["flow_links"].shape[0]
     rows = static["occ_rows"][fid]                           # (P,)
@@ -148,11 +117,9 @@ def _dedupe_ascending(vals, k, sentinel):
     return jnp.full((k,), sentinel, s.dtype).at[slot].min(s)
 
 
-def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links,
-                 legacy=False):
+def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links):
     """Snapshot link set (deduped, padded) + edge list — all snapshot-sized
-    (SF·P), no full-arena pass. `legacy=True` reproduces the seed program's
-    jnp.unique dedupe (same output, slower lowering on CPU).
+    (SF·P), no full-arena pass.
 
     An edge's slot is its link's rank in the sorted set, counted by one
     (SF·P, SL) comparison: a binary search lowers to a `while` loop of
@@ -160,10 +127,7 @@ def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links,
     SF, P, SL = cfg.snap_flows, cfg.max_path, cfg.snap_links
     gl = flow_links[snap_f]                                  # (SF, P)
     gl = jnp.where((gl >= 0) & (snap_f_mask[:, None] > 0), gl, num_links)
-    if legacy:
-        uniq = jnp.unique(gl.reshape(-1), size=SL, fill_value=num_links)
-    else:
-        uniq = _dedupe_ascending(gl.reshape(-1), SL, num_links)
+    uniq = _dedupe_ascending(gl.reshape(-1), SL, num_links)
     snap_l = uniq
     snap_l_mask = (uniq < num_links).astype(jnp.float32)
     el = (uniq[None, :] < gl.reshape(-1, 1)).sum(-1, dtype=jnp.int32)
@@ -172,24 +136,15 @@ def _build_links(cfg: M4Config, flow_links, snap_f, snap_f_mask, num_links,
     return snap_l, snap_l_mask, el, edge_mask
 
 
-def make_event_step(cfg: M4Config, static, num_links: int,
-                    snapshot_impl: str = "incremental"):
+def make_event_step(cfg: M4Config, static, num_links: int):
     """static: dict of arena constant arrays (flow_links, flow_feat,
     link_feat, ideal_fct, t_arrival, cfg_vec, link_members, occ_rows,
     occ_slots); num_links is static.
 
-    `snapshot_impl` selects the whole event-step program:
-      "incremental"  production — O(P·K) snapshot from the occupancy
-                     arenas, dump-row-redirected scatter-back, GNN/GRU via
-                     the kernel dispatch.
-      "dense"        the seed program, kept as the equivalence/benchmark
-                     oracle — O(N·P²) dense candidate search, blend-style
-                     scatter-back, segment-sum GNN. perf_gate measures it
-                     as the "current main" baseline; tests assert the two
-                     emit matching snapshots and FCTs.
+    One event: an O(P·K) snapshot from the occupancy arenas, the GRU and
+    GNN through the kernel dispatch, and a scatter-back that redirects
+    masked slots to the dump rows.
     """
-    assert snapshot_impl in ("incremental", "dense"), snapshot_impl
-    legacy = snapshot_impl == "dense"
     SF, P = cfg.snap_flows, cfg.max_path
     edge_f = jnp.repeat(jnp.arange(SF, dtype=jnp.int32), P)
 
@@ -203,23 +158,16 @@ def make_event_step(cfg: M4Config, static, num_links: int,
         cfg_vec = static["cfg_vec"]
         N = flow_links.shape[0]
         with jax.named_scope("m4.snapshot"):
-            if legacy:
-                active = (state["arrived"] & ~state["done"])[:N]
-                active = active.at[fid].set(True)  # arriving flow counts
-                snap_f, sfm = _build_snapshot_dense(cfg, flow_links, fid,
-                                                    active)
-            else:
-                snap_f, sfm = _build_snapshot(cfg, static, state["link_occ"],
-                                              fid)
-                # occupancy arenas: the event flow enters (arrival) /
-                # leaves (departure) the membership slots of its own
-                # links — O(P)
-                state["link_occ"] = state["link_occ"].at[
-                    static["occ_rows"][fid],
-                    static["occ_slots"][fid]].set(is_arrival)
+            snap_f, sfm = _build_snapshot(cfg, static, state["link_occ"],
+                                          fid)
+            # occupancy arenas: the event flow enters (arrival) / leaves
+            # (departure) the membership slots of its own links — O(P)
+            state["link_occ"] = state["link_occ"].at[
+                static["occ_rows"][fid],
+                static["occ_slots"][fid]].set(is_arrival)
             fgather = jnp.minimum(snap_f, N - 1)  # clamped (masked out)
             snap_l, slm, edge_l, edge_mask = _build_links(
-                cfg, flow_links, fgather, sfm, num_links, legacy=legacy)
+                cfg, flow_links, fgather, sfm, num_links)
             sl_safe = jnp.minimum(snap_l, num_links)  # dump row = num_links
             lgather = jnp.minimum(snap_l, num_links - 1)
 
@@ -240,12 +188,10 @@ def make_event_step(cfg: M4Config, static, num_links: int,
 
         with jax.named_scope("m4.temporal"):
             f_h, l_h = temporal_update(params, cfg, f_h, l_h, dt_f, dt_l,
-                                       f_feat, l_feat, cfg_vec,
-                                       ref_impl=legacy)
+                                       f_feat, l_feat, cfg_vec)
         with jax.named_scope("m4.spatial"):
             f_h2, l_h2 = spatial_update(params, cfg, f_h, l_h, edge_f,
-                                        edge_l, edge_mask, cfg_vec,
-                                        ref_impl=legacy)
+                                        edge_l, edge_mask, cfg_vec)
         with jax.named_scope("m4.heads"):
             sldn = predict_sldn(params, f_h2,
                                 static["flow_feat"][fgather, 1] * 8.0,
@@ -256,33 +202,18 @@ def make_event_step(cfg: M4Config, static, num_links: int,
             t_dep_new = jnp.maximum(t_dep_new, t_ev + 1e-9)
 
         with jax.named_scope("m4.scatter"):
-            if legacy:
-                # seed-style blend scatter: read-modify-write of the arenas
-                wf = sfm[:, None]
-                state["flow_h"] = state["flow_h"].at[snap_f].set(
-                    wf * f_h2 + (1 - wf) * state["flow_h"][snap_f])
-                wl = (slm[:, None])
-                state["link_h"] = state["link_h"].at[sl_safe].set(
-                    wl * l_h2 + (1 - wl) * state["link_h"][sl_safe])
-                state["flow_last"] = state["flow_last"].at[snap_f].set(
-                    jnp.where(sfm > 0, t_ev, state["flow_last"][snap_f]))
-                state["link_last"] = state["link_last"].at[sl_safe].set(
-                    jnp.where(slm > 0, t_ev, state["link_last"][sl_safe]))
-                state["t_dep"] = state["t_dep"].at[snap_f].set(
-                    jnp.where(sfm > 0, t_dep_new, state["t_dep"][snap_f]))
-            else:
-                # scatter back with masked slots *redirected to the dump
-                # row* (index N / num_links) instead of blending old values
-                # back in — live rows receive exactly f_h2/l_h2, the dump
-                # row absorbs the rest, and the arenas update without a
-                # read-modify-write of the whole (N, H) buffer
-                idx_f = jnp.where(sfm > 0, snap_f, N)
-                idx_l = jnp.where(slm > 0, sl_safe, num_links)
-                state["flow_h"] = state["flow_h"].at[idx_f].set(f_h2)
-                state["link_h"] = state["link_h"].at[idx_l].set(l_h2)
-                state["flow_last"] = state["flow_last"].at[idx_f].set(t_ev)
-                state["link_last"] = state["link_last"].at[idx_l].set(t_ev)
-                state["t_dep"] = state["t_dep"].at[idx_f].set(t_dep_new)
+            # scatter back with masked slots *redirected to the dump row*
+            # (index N / num_links) instead of blending old values back in
+            # — live rows receive exactly f_h2/l_h2, the dump row absorbs
+            # the rest, and the arenas update without a read-modify-write
+            # of the whole (N, H) buffer
+            idx_f = jnp.where(sfm > 0, snap_f, N)
+            idx_l = jnp.where(slm > 0, sl_safe, num_links)
+            state["flow_h"] = state["flow_h"].at[idx_f].set(f_h2)
+            state["link_h"] = state["link_h"].at[idx_l].set(l_h2)
+            state["flow_last"] = state["flow_last"].at[idx_f].set(t_ev)
+            state["link_last"] = state["link_last"].at[idx_l].set(t_ev)
+            state["t_dep"] = state["t_dep"].at[idx_f].set(t_dep_new)
         return state, sldn, (snap_f, sfm)
 
     return event_step
@@ -329,9 +260,8 @@ def _probe_values(params, static, state, N, num_links):
         return predict_queue(params, state["link_h"][:num_links])
 
     def link_active():
-        # active-flow count per link via the static path->slot tables —
-        # works for both snapshot impls (the dense path never maintains
-        # link_occ); invalid path slots scatter onto the dump row
+        # active-flow count per link via the static path->slot tables;
+        # invalid path slots scatter onto the dump row
         rows = static["occ_rows"]                            # (N, P)
         cnt = jnp.zeros((num_links + 1,), jnp.float32).at[rows].add(
             jnp.broadcast_to(active()[:, None], rows.shape))
@@ -347,14 +277,12 @@ def _probe_values(params, static, state, N, num_links):
 
 
 def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
-                    arr_times, snapshot_impl="incremental", num_events=None,
-                    probes=None):
+                    arr_times, num_events=None, probes=None):
     N = arr_times.shape[0]
-    legacy = snapshot_impl == "dense"
     # the kernels' weight layout, built here once and carried through the
     # scan as loop-invariant values rather than rebuilt in every event
     params = stage_params(params, resolve_mode(cfg.kernel_mode))
-    step = make_event_step(cfg, static, num_links, snapshot_impl)
+    step = make_event_step(cfg, static, num_links)
     state = init_sim_state(params, cfg, static, N, num_links)
 
     def body(carry, _):
@@ -362,16 +290,11 @@ def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
         with jax.named_scope("m4.departure"):
             next_arr = jnp.where(ptr < N, arr_times[jnp.minimum(ptr, N - 1)],
                                  BIG)
-            if legacy:
-                dep_t = jnp.where(state["arrived"] & ~state["done"],
-                                  state["t_dep"], BIG)[:N]
-            else:
-                # invariant: t_dep rows < N are finite exactly for flows
-                # that are arrived-and-not-done (init BIG, arrival/snapshot
-                # updates touch only active rows, departure resets to BIG),
-                # so the departure race reads the carry directly — no mask
-                # gathers
-                dep_t = state["t_dep"][:N]
+            # invariant: t_dep rows < N are finite exactly for flows that
+            # are arrived-and-not-done (init BIG, arrival/snapshot updates
+            # touch only active rows, departure resets to BIG), so the
+            # departure race reads the carry directly — no mask gathers
+            dep_t = state["t_dep"][:N]
             dep_i = jnp.argmin(dep_t)
             next_dep = dep_t[dep_i]
             is_arr = next_arr <= next_dep
@@ -380,27 +303,15 @@ def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
 
         state, _, _ = step(params, state, t_ev, fid, is_arr)
         with jax.named_scope("m4.scatter"):
-            if legacy:
-                state["arrived"] = state["arrived"].at[fid].set(
-                    state["arrived"][fid] | is_arr)
-                state["done"] = state["done"].at[fid].set(
-                    state["done"][fid] | ~is_arr)
-                state["fct"] = state["fct"].at[fid].set(
-                    jnp.where(is_arr, state["fct"][fid],
-                              t_ev - state["t_arr"][fid]))
-                state["t_dep"] = state["t_dep"].at[fid].set(
-                    jnp.where(is_arr, state["t_dep"][fid], BIG))
-            else:
-                # every event at fid implies "arrived"; "done" iff
-                # departure — plain sets, no read-modify-write; arrival-
-                # event writes of fct / t_dep redirect to the dump row
-                # instead of blending
-                fid_or_dump = jnp.where(is_arr, N, fid)
-                state["arrived"] = state["arrived"].at[fid].set(True)
-                state["done"] = state["done"].at[fid].set(~is_arr)
-                state["fct"] = state["fct"].at[fid_or_dump].set(
-                    t_ev - state["t_arr"][fid])
-                state["t_dep"] = state["t_dep"].at[fid_or_dump].set(BIG)
+            # every event at fid implies "arrived"; "done" iff departure
+            # — plain sets, no read-modify-write; arrival-event writes of
+            # fct / t_dep redirect to the dump row instead of blending
+            fid_or_dump = jnp.where(is_arr, N, fid)
+            state["arrived"] = state["arrived"].at[fid].set(True)
+            state["done"] = state["done"].at[fid].set(~is_arr)
+            state["fct"] = state["fct"].at[fid_or_dump].set(
+                t_ev - state["t_arr"][fid])
+            state["t_dep"] = state["t_dep"].at[fid_or_dump].set(BIG)
             ptr = ptr + is_arr.astype(jnp.int32)
         return (state, ptr, t_ev), None
 
@@ -428,27 +339,26 @@ def _open_loop_core(params, cfg: M4Config, num_links: int, static, arr_order,
 
 
 @partial(jax.jit, static_argnums=(1, 2),
-         static_argnames=("snapshot_impl", "num_events", "probes"))
+         static_argnames=("num_events", "probes"))
 def _open_loop_scan(params, cfg: M4Config, num_links: int, static, arr_order,
-                    arr_times, snapshot_impl="incremental", num_events=None,
-                    probes=None):
+                    arr_times, num_events=None, probes=None):
     TRACE_COUNTS["open_loop"] += 1
     return _open_loop_core(params, cfg, num_links, static, arr_order,
-                           arr_times, snapshot_impl, num_events, probes)
+                           arr_times, num_events, probes)
 
 
 @partial(jax.jit, static_argnums=(1, 2),
-         static_argnames=("snapshot_impl", "num_events", "probes"))
+         static_argnames=("num_events", "probes"))
 def _open_loop_scan_batched(params, cfg: M4Config, num_links: int, static,
-                            arr_order, arr_times, snapshot_impl="incremental",
-                            num_events=None, probes=None):
+                            arr_order, arr_times, num_events=None,
+                            probes=None):
     """vmap of the open-loop scan over B scenarios padded to one arena shape.
     Scenario axes: every leaf of `static`, plus arr_order/arr_times."""
     TRACE_COUNTS["open_loop_batched"] += 1
 
     def one(s, o, t):
         return _open_loop_core(params, cfg, num_links, s, o, t,
-                               snapshot_impl, num_events, probes)
+                               num_events, probes)
 
     return jax.vmap(one)(static, arr_order, arr_times)
 
@@ -599,14 +509,12 @@ def _arrival_order(static):
 
 
 def simulate_open_loop(params, cfg: M4Config, topo, net_config, flows, *,
-                       snapshot_impl="incremental",
                        probes: ProbeConfig = None) -> M4Result:
     """One scenario through the open-loop scan.
 
-    `snapshot_impl="dense"` switches to the reference builder
-    (tests/benchmark comparisons only). `probes` (a static `ProbeConfig`)
-    additionally records intermediate-state time series into
-    `M4Result.probes`; None compiles the identical probe-free program.
+    `probes` (a static `ProbeConfig`) additionally records intermediate-
+    state time series into `M4Result.probes`; None compiles the identical
+    probe-free program.
     The call is the `m4.run` span, with the children `m4.build`,
     `m4.scan` and `m4.result` (`repro.obs`)."""
     tracer = get_tracer()
@@ -621,8 +529,7 @@ def simulate_open_loop(params, cfg: M4Config, topo, net_config, flows, *,
                     jnp.asarray(times))
         with tracer.span("m4.scan"):
             t0 = time.perf_counter()
-            out = _open_loop_scan(*args, snapshot_impl=snapshot_impl,
-                                  probes=probes)
+            out = _open_loop_scan(*args, probes=probes)
             out = jax.block_until_ready(out)
             wall = time.perf_counter() - t0
         with tracer.span("m4.result"):
@@ -682,7 +589,6 @@ def stack_scenarios(cfg: M4Config, scenarios, shape=None):
 
 
 def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
-                             snapshot_impl="incremental",
                              probes: ProbeConfig = None,
                              devices=None) -> list:
     """Run many scenarios in ONE compiled vmapped scan.
@@ -723,8 +629,7 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
         counts = [n for n, _, _ in shape]
         n_max = max(counts)
         D = jax.local_device_count() if devices is None else len(devices)
-        sharded = (D > 1 and len(scenarios) >= D
-                   and snapshot_impl == "incremental" and probes is None)
+        sharded = D > 1 and len(scenarios) >= D and probes is None
         bufs = None
         with tracer.span("m4.scan"):
             t0 = time.perf_counter()
@@ -739,8 +644,7 @@ def simulate_open_loop_batch(params, cfg: M4Config, scenarios, *,
                 if devices is not None:
                     args = jax.device_put(args, devices[0])
                 res = _open_loop_scan_batched(
-                    args[0], cfg, l_max, *args[1:],
-                    snapshot_impl=snapshot_impl, probes=probes)
+                    args[0], cfg, l_max, *args[1:], probes=probes)
             res = jax.block_until_ready(res)
             wall = time.perf_counter() - t0
         with tracer.span("m4.result"):

@@ -1,5 +1,6 @@
-"""Pure-jnp oracle for the bipartite GraphSAGE round (segment-sum form —
-identical math to `repro.core.model._bipartite_round`)."""
+"""Pure-jnp oracle for the bipartite GraphSAGE round: the segment-sum
+form (`bipartite_round_ref`) that the incidence-matmul and Pallas paths
+are tested against."""
 from __future__ import annotations
 
 import jax

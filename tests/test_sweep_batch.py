@@ -3,9 +3,9 @@ Table-2 scenarios that differ in everything the batch pads over: CC
 scheme and knobs, spine count (links), flow count (events) and link
 degree (K).
 
-- each lane agrees with that scenario's own `run`, and with the plain
-  reference (`bench/systems/m4_ref.py`, dense jax.numpy at `highest`),
-  run on the scenario alone, unpadded;
+- each lane agrees with that scenario's own `run`, and both agree with
+  the plain reference (`bench/systems/m4_ref.py`, dense jax.numpy at
+  `highest`), run on the scenario alone, unpadded;
 - the batch gauges (`m4.batch.*`) equal the padding counted by hand;
 - `devices` holds a batch to one device (the vmapped scan there) on a
   host with two, and the default still shards.
@@ -69,7 +69,8 @@ def batch():
     reqs = [common.to_request(s) for s in scens]
     return {"scens": scens, "params": params, "backend": backend,
             "reqs": reqs, "batched": backend.run_many(
-                reqs, devices=jax.devices()[:1])}
+                reqs, devices=jax.devices()[:1]),
+            "run": [backend.run(r) for r in reqs]}
 
 
 def test_points_differ_in_every_padded_axis():
@@ -82,20 +83,22 @@ def test_points_differ_in_every_padded_axis():
 @pytest.mark.parametrize("lane", range(len(POINTS)))
 def test_lane_matches_its_own_run(batch, lane):
     got = batch["batched"][lane].fcts
-    want = batch["backend"].run(batch["reqs"][lane]).fcts
+    want = batch["run"][lane].fcts
     assert got.shape == (POINTS[lane]["num_flows"],)
     np.testing.assert_allclose(got, want, rtol=1e-5)
 
 
 @pytest.mark.parametrize("lane", range(len(POINTS)))
-def test_lane_matches_the_plain_reference(batch, lane):
-    """The reference runs the scenario alone, unpadded. The limit is the
-    tiny sweep cell's (bench/tests/test_sweep.py): on CPU seeds 1-16 its
-    worst lane read at most 3.0e-8, the control one precision lower at
-    least 9.6e-8."""
+@pytest.mark.parametrize("path", ["batched", "run"])
+def test_lane_matches_the_plain_reference(batch, path, lane):
+    """Each lane of `run_many` ("batched") and each scenario's own `run`
+    against the reference, which runs the scenario alone, unpadded. The
+    limit is the tiny sweep cell's (bench/tests/test_sweep.py): on CPU
+    seeds 1-16 its worst lane read at most 3.0e-8, the control one
+    precision lower at least 9.6e-8."""
     ref, full = m4_ref.simulate(batch["params"], batch["scens"][lane], MODEL,
                                 "highest")
-    got = batch["batched"][lane].fcts
+    got = batch[path][lane].fcts
     assert common.unfinished(got) == 0
     assert full == {"flows_overflow": 0, "links_overflow": 0}
     assert common.fct_gap_mean(got, ref) < 5e-8
