@@ -10,6 +10,10 @@ matmuls** that run on the MXU:
     f_new = relu([f_emb ; agg_f] @ Wf + bf)
     l_new = relu([l_emb ; agg_l] @ Wl + bl)
 
+M is an input, not built here: `ops.bipartite_rounds` builds it once per
+event from the edge list (`ref.incidence_from_edges`, a one-hot
+contraction) and passes the same M to all rounds.
+
 Everything for one snapshot fits VMEM (SF=64, SL=128, G=304 padded:
 ~3 MB at f32), so the whole round is a single fused kernel; the grid tiles
 the output feature dimension to keep per-program VMEM bounded and MXU

@@ -6,7 +6,9 @@ Two steps: `stage_round` lays one round's weights out as the kernel takes
 them, and `bipartite_round_staged` pads the embeddings and calls the
 kernel. A scan that runs the rounds every step stages once, outside the
 loop (`repro.kernels.dispatch.stage_params`); `bipartite_round` does both
-per call."""
+per call. The kernel takes the incidence M (SF, SL) as an input:
+`bipartite_rounds` builds it once per event with `ref.incidence_from_edges`
+(the builder "xla" mode uses too) and hands the same M to every round."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -39,18 +41,24 @@ def stage_round(wf, wl, bf, bl):
             "bf": _pad_to(bf, 128, 0)[None], "bl": _pad_to(bl, 128, 0)[None]}
 
 
-def bipartite_round_staged(f_emb, l_emb, edge_f, edge_l, edge_mask, w, *,
-                           interpret=True):
-    """One round on weights staged by `stage_round`: pads the embeddings
-    (·, G) to the staged width, runs the kernel, slices back."""
-    SF, G = f_emb.shape
-    SL = l_emb.shape[0]
-    m = incidence_from_edges(edge_f, edge_l, edge_mask, SF, SL)
+def _round_on_incidence(f_emb, l_emb, m, w, *, interpret):
+    """One round on the incidence `m` and weights staged by `stage_round`:
+    pads the embeddings (·, G) to the staged width, runs the kernel,
+    slices back."""
+    G = f_emb.shape[1]
     fp = _pad_to(f_emb, 128, 1)
     lp = _pad_to(l_emb, 128, 1)
     fo, lo = bipartite_round_pallas(fp, lp, m, w["wf"], w["wl"], w["bf"],
                                     w["bl"], interpret=interpret)
     return fo[:, :G], lo[:, :G]
+
+
+def bipartite_round_staged(f_emb, l_emb, edge_f, edge_l, edge_mask, w, *,
+                           interpret=True):
+    """One round on weights staged by `stage_round`, from the edge list."""
+    m = incidence_from_edges(edge_f, edge_l, edge_mask, f_emb.shape[0],
+                             l_emb.shape[0])
+    return _round_on_incidence(f_emb, l_emb, m, w, interpret=interpret)
 
 
 def bipartite_round(f_emb, l_emb, edge_f, edge_l, edge_mask, wf, wl, bf, bl,
@@ -63,8 +71,10 @@ def bipartite_round(f_emb, l_emb, edge_f, edge_l, edge_mask, wf, wl, bf, bl,
 
 def bipartite_rounds(staged_layers, f, l, edge_f, edge_l, edge_mask, *,
                      interpret=True):
-    """Multi-round GNN used by m4's spatial model, on `stage_round`s."""
+    """Multi-round GNN used by m4's spatial model, on `stage_round`s: the
+    incidence is built once and every round reads it."""
+    m = incidence_from_edges(edge_f, edge_l, edge_mask, f.shape[0],
+                             l.shape[0])
     for w in staged_layers:
-        f, l = bipartite_round_staged(f, l, edge_f, edge_l, edge_mask, w,
-                                      interpret=interpret)
+        f, l = _round_on_incidence(f, l, m, w, interpret=interpret)
     return f, l

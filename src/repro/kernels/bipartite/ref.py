@@ -1,6 +1,8 @@
-"""Pure-jnp oracle for the bipartite GraphSAGE round: the segment-sum
-form (`bipartite_round_ref`) that the incidence-matmul and Pallas paths
-are tested against."""
+"""Pure-jnp forms of the bipartite GraphSAGE round: the segment-sum
+oracle (`bipartite_round_ref`) that the incidence-matmul and Pallas paths
+are tested against, the incidence builder both paths share
+(`incidence_from_edges`), and the incidence-matmul rounds of "xla" mode
+(`bipartite_rounds_matmul`)."""
 from __future__ import annotations
 
 import jax
@@ -8,9 +10,24 @@ import jax.numpy as jnp
 
 
 def incidence_from_edges(edge_f, edge_l, edge_mask, SF, SL):
-    """Edge list -> dense 0/1 incidence matrix (SF, SL)."""
-    m = jnp.zeros((SF, SL), jnp.float32)
-    return m.at[edge_f, edge_l].add(edge_mask)
+    """Edge list -> dense incidence matrix M (SF, SL): M[f, l] counts the
+    unmasked edges from flow slot f to link slot l.
+
+    The one incidence builder of the GNN, built once per event and handed
+    to every round, in "xla" mode (`dispatch.gnn_rounds`) and in the Pallas
+    modes (`ops.bipartite_rounds`). It is a contraction of two one-hot
+    matrices, (E, SF) and (E, SL)·mask, not a scatter-add: the TPU applies
+    a scatter's updates one at a time. 0/1 inputs and f32 accumulation
+    keep the small integer counts exact at any matmul precision. Written
+    as (loᵀ·fo)ᵀ so that under `vmap`, where the event step's `edge_f` is
+    one constant for every lane, the batch axis of M stays leading
+    (fo.T @ lo puts it in the middle, which the kernel's block spec
+    refuses)."""
+    fo = (edge_f[:, None] == jnp.arange(SF, dtype=edge_f.dtype)[None, :]
+          ).astype(jnp.float32)
+    lo = (edge_l[:, None] == jnp.arange(SL, dtype=edge_l.dtype)[None, :]
+          ).astype(jnp.float32) * edge_mask[:, None]
+    return (lo.T @ fo).T
 
 
 def bipartite_round_ref(f_emb, l_emb, edge_f, edge_l, edge_mask, wf, wl, bf, bl):

@@ -205,18 +205,14 @@ def gnn_rounds(layers, f, l, edge_f, edge_l, edge_mask, num_links, *,
     """Multi-round bipartite GraphSAGE (m4's spatial model). `layers`
     are `params["gnn"]` in "xla" mode, else their `stage_params` layout."""
     if mode == "xla":
-        import jax.numpy as jnp
-        from .bipartite.ref import bipartite_rounds_matmul
-        # incidence built once per event with one-hot matmuls (no scatter),
-        # then every round is dense matmuls — the kernel's formulation run
-        # by XLA; segment-sum survives as the oracle in bipartite/ref.py
-        SF, SL = f.shape[0], l.shape[0]
-        fo = (edge_f[:, None]
-              == jnp.arange(SF, dtype=jnp.int32)[None, :]).astype(f.dtype)
-        lo = (edge_l[:, None]
-              == jnp.arange(SL, dtype=jnp.int32)[None, :]).astype(f.dtype) \
-            * edge_mask[:, None]
-        return bipartite_rounds_matmul(layers, f, l, fo.T @ lo)
+        from .bipartite.ref import bipartite_rounds_matmul, incidence_from_edges
+        # the incidence, built once per event by the builder the Pallas
+        # path shares (one-hot contraction, no scatter); then every round
+        # is dense matmuls — the kernel's formulation run by XLA.
+        # segment-sum survives as the oracle in bipartite/ref.py
+        m = incidence_from_edges(edge_f, edge_l, edge_mask, f.shape[0],
+                                 l.shape[0])
+        return bipartite_rounds_matmul(layers, f, l, m)
     from .bipartite.ops import bipartite_rounds
     return bipartite_rounds(layers, f, l, edge_f, edge_l, edge_mask,
                             interpret=mode != "pallas")

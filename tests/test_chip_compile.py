@@ -261,6 +261,43 @@ def test_open_loop_scan_has_no_nested_loop(scan_hlo, batched):
     assert not named, named[:4]
 
 
+def _incidence_shapes(batched):
+    """The GNN incidence M (SF, SL) as the compiled scan holds it: (B, SF,
+    SL) under `vmap`, or flattened to (B·SF·SL,)."""
+    SF, SL = PAPER.snap_flows, PAPER.snap_links
+    if not batched:
+        return {f"f32[{SF},{SL}]"}
+    B = BATCH["B"]
+    return {f"f32[{B},{SF},{SL}]", f"f32[{B * SF * SL}]"}
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_open_loop_scan_builds_incidence_once(scan_hlo, batched):
+    """The GNN incidence is a one-hot contraction built once per event: no
+    scatter in the event loop's body produces it (the TPU applies a
+    scatter's updates one at a time), and the three
+    `bipartite_round_pallas` calls of an event take the same M."""
+    comps, entry = _hlo_computations(scan_hlo(batched))
+    loop = _loop_computations(comps, entry)
+    shapes = _incidence_shapes(batched)
+    scatters = sorted(f"{c}: {name} = {ins['shape']}" for c in loop
+                      for name, ins in comps[c].items()
+                      if ins["op"] == "scatter" and ins["shape"] in shapes)
+    assert not scatters, scatters[:4]
+    m_operands = []
+    for c in loop:
+        body = comps[c]
+        for name, ins in body.items():
+            if name.rsplit(".", 1)[0] != "bipartite_round_pallas":
+                continue
+            src = ins["operands"][2]
+            while body[src]["op"] in ("copy-start", "copy-done"):
+                src = body[src]["operands"][0]
+            assert body[src]["shape"] in shapes, (name, src)
+            m_operands.append((c, src))
+    assert len(m_operands) == 3 and len(set(m_operands)) == 1, m_operands
+
+
 @pytest.mark.parametrize("batched", [False, True])
 def test_flowsim_fast_scan_compiles(one_chip, batched):
     B, N, L = BATCH["B"], BATCH["N"], BATCH["L"]

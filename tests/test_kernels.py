@@ -248,6 +248,74 @@ def test_staged_gnn_round_matches_bipartite_round(SF, SL, G, P):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def _incidence_case(case):
+    """-> (SF, SL, edge_f, edge_l, edge_mask) as the event step lays an
+    edge list out: flow slot f owns edges f·P .. f·P + P - 1."""
+    name, SF, SL, P = case
+    rng = np.random.default_rng(SF * SL + P)
+    edge_f = np.repeat(np.arange(SF, dtype=np.int32), P)
+    edge_l = rng.integers(0, SL, SF * P).astype(np.int32)
+    edge_mask = (rng.random(SF * P) < 0.7).astype(np.float32)
+    if name == "repeated-link":         # flow 0 lists link 3 twice
+        edge_l[:2], edge_mask[:2] = 3, 1.0
+    elif name == "all-masked":
+        edge_mask[:] = 0.0
+    elif name == "clamped":
+        # more distinct links than SL slots: `_build_links` clamps the
+        # edges of links ranked past the set onto slot SL - 1
+        from repro.core.model import M4Config
+        from repro.core.simulate import _build_links
+        cfg = M4Config(snap_flows=SF, snap_links=SL, max_path=P)
+        flow_links = rng.permutation(4 * SF * P)[:SF * P].reshape(SF, P)
+        flow_links[-1, -1] = -1                         # a short path
+        _, _, el, em = _build_links(cfg, jnp.asarray(flow_links, jnp.int32),
+                                    jnp.arange(SF, dtype=jnp.int32),
+                                    jnp.ones((SF,), jnp.float32), 4 * SF * P)
+        edge_l, edge_mask = np.asarray(el), np.asarray(em)
+        assert ((edge_l == SL - 1) & (edge_mask > 0)).sum() > 1
+    return SF, SL, edge_f, edge_l, edge_mask
+
+
+INCIDENCE_CASES = [("random", 8, 16, 4), ("random", 64, 128, 8),
+                   ("repeated-link", 8, 16, 4), ("all-masked", 8, 16, 4),
+                   ("clamped", 8, 16, 4)]
+
+
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+@pytest.mark.parametrize("case", INCIDENCE_CASES,
+                         ids=lambda c: f"{c[0]}-{c[1]}x{c[2]}-P{c[3]}")
+def test_incidence_builder_matches_scatter_oracle(case, mode):
+    """The one incidence builder (a one-hot contraction) is bit-equal to a
+    scatter-add of the unmasked edges, and the GNN round of `mode` reads
+    exactly that matrix: with identity embeddings and weights [0; I] the
+    round returns relu(M) = M for the flows and Mᵀ for the links, counts
+    that no summation order can round."""
+    from repro.kernels import dispatch
+    from repro.kernels.bipartite.ops import stage_round
+    from repro.kernels.bipartite.ref import incidence_from_edges
+    SF, SL, edge_f, edge_l, edge_mask = _incidence_case(case)
+    want = np.zeros((SF, SL), np.float32)
+    np.add.at(want, (edge_f, edge_l), edge_mask)
+    if case[0] == "repeated-link":
+        assert want[0, 3] == 2.0
+    got = jax.jit(incidence_from_edges, static_argnums=(3, 4))(
+        edge_f, edge_l, edge_mask, SF, SL)
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+    G = SL
+    probe = jnp.concatenate([jnp.zeros((G, G)), jnp.eye(G)])    # [0; I]
+    zero = jnp.zeros((G,))
+    layers = ([{"wf": {"w": probe, "b": zero}, "wl": {"w": probe, "b": zero}}]
+              if mode == "xla" else [stage_round(probe, probe, zero, zero)])
+    run = jax.jit(dispatch.gnn_rounds, static_argnames=("num_links", "mode"))
+    out_f, out_l = run(layers, jnp.eye(SF, G), jnp.eye(SL, G),
+                       jnp.asarray(edge_f), jnp.asarray(edge_l),
+                       jnp.asarray(edge_mask), num_links=SL, mode=mode)
+    np.testing.assert_array_equal(np.asarray(out_f), want)
+    np.testing.assert_array_equal(np.asarray(out_l)[:, :SF], want.T)
+    np.testing.assert_array_equal(np.asarray(out_l)[:, SF:], 0.0)
+
+
 SMALL = dict(hidden=16, gnn_dim=12, mlp_hidden=8, gnn_layers=2,
              snap_flows=8, snap_links=24)
 
