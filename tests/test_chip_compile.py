@@ -319,3 +319,82 @@ def test_xla_mode_scan_compiles(one_chip):
                    _static(one_chip, cfg, N, L, SINGLE["K"]),
                    _sds(one_chip, (N,), jnp.int32), _sds(one_chip, (N,)))
     assert "tpu_custom_call" not in hlo
+
+
+# ------------------------------------------------------ flowsim_fast's scan
+FLOWSIM_SCOPES = ("flowsim.waterfill", "flowsim.linksum", "flowsim.rowmin",
+                  "flowsim.advance")
+COMPUTE_FREE = PASS_ON | {"constant"}
+
+
+@pytest.fixture(scope="module")
+def flowsim_hlo(one_chip):
+    """The compiled text of flowsim_fast's single-scenario scan in pallas
+    mode at the benchmark cell's size (4,096 flows, 1,536 links), as
+    `_event_scan` compiles it and with its rounds count dropped."""
+    N, L = SINGLE["N"], SINGLE["L"]
+    args = (_sds(one_chip, (N, L)), _sds(one_chip, (L,)),
+            _sds(one_chip, (N,)), _sds(one_chip, (N,)),
+            _sds(one_chip, (N,), jnp.int32))
+    uncounted = jax.jit(lambda *a: ff._event_scan_core(*a, "pallas")[0])
+    return {"counted": _compile(ff._event_scan, *args, mode="pallas"),
+            "uncounted": _compile(uncounted, *args)}
+
+
+def _reachable(comps, roots):
+    todo, seen = list(roots), set()
+    while todo:
+        c = todo.pop()
+        if c in comps and c not in seen:
+            seen.add(c)
+            todo += [d for ins in comps[c].values() for d in ins["called"]]
+    return seen
+
+
+def _flowsim_loops(text):
+    """(computations of the event loop outside the water-filling loop,
+    computations of the water-filling loop) of a compiled flowsim scan."""
+    comps, entry = _hlo_computations(text)
+    loop = _loop_computations(comps, entry)
+    inner = _reachable(comps, [c for name in loop
+                               for ins in comps[name].values()
+                               if ins["op"] == "while"
+                               for c in ins["called"]])
+    assert inner, "no water-filling loop inside the event loop"
+    return comps, loop - inner, inner
+
+
+def _ops(comps, names):
+    return Counter((ins["op"], ins["shape"]) for c in names
+                   for ins in comps[c].values()
+                   if ins["op"] not in COMPUTE_FREE)
+
+
+def test_flowsim_scan_names_its_layers(flowsim_hlo):
+    """The four event-step scopes reach the compiled ops' metadata, where
+    the profiler's name stacks come from."""
+    names = re.findall(r'op_name="([^"]*)"', flowsim_hlo["counted"])
+    for scope in FLOWSIM_SCOPES:
+        assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_flowsim_link_sums_are_f32_reductions(flowsim_hlo):
+    """The water-filling round computes both link sums as float32
+    reductions over the flows, with no `dot`: at default precision a
+    `dot` would take one bfloat16 pass over rates near 1e10 bit/s."""
+    comps, _, inner = _flowsim_loops(flowsim_hlo["counted"])
+    ops = _ops(comps, inner)
+    assert not [k for k in ops if k[0] in ("dot", "convolution")], ops
+    assert ops[("reduce", f"f32[{SINGLE['L']}]")] == 2, ops
+
+
+def test_flowsim_rounds_counter_costs_one_add(flowsim_hlo):
+    """Counting the water-filling rounds adds no op to the round's body
+    and one s32 add to the event's; the rest is the loop carrying it."""
+    c_comps, c_outer, c_inner = _flowsim_loops(flowsim_hlo["counted"])
+    u_comps, u_outer, u_inner = _flowsim_loops(flowsim_hlo["uncounted"])
+    assert _ops(c_comps, c_inner) == _ops(u_comps, u_inner)
+    extra = _ops(c_comps, c_outer)
+    extra.subtract(_ops(u_comps, u_outer))
+    assert +extra == Counter({("add", "s32[]"): 1}), extra
+    assert -extra == Counter(), extra
